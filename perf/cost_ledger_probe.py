@@ -50,7 +50,7 @@ Cells (kind ``cpu`` — the tier-1 gate re-derives all of them):
   overflow degrades to the host oracle (counted, never an assert),
   eviction/restore thrash pinned, convergence asserted.
 
-``--device`` (perf/when_up_r11.sh) appends the silicon cells — wall
+``--device`` (run on the chip) appends the silicon cells — wall
 histograms + real-HLO costs on the default backend, plus the flow
 cell's device variant (logical ages must reproduce EXACTLY on chip) —
 without touching the cpu cells; the gate skips ``kind: device`` cells
@@ -613,11 +613,11 @@ def cell_sp():
 
 
 def cell_serve_device():
-    """Silicon cell (perf/when_up_r11.sh): the same small loadgen on
-    the DEFAULT jax backend — per-bucket device-step wall histograms
-    plus the real-HLO flat-kernel costs.  Wall metrics carry wide bands
-    (they gate nothing on CPU; the cell is the committed record of what
-    the chip measured)."""
+    """Silicon cell (``--device``, on the chip): the same small
+    loadgen on the DEFAULT jax backend — per-bucket device-step wall
+    histograms plus the real-HLO flat-kernel costs.  Wall metrics carry
+    wide bands (they gate nothing on CPU; the cell is the committed
+    record of what the chip measured)."""
     import jax
 
     from text_crdt_rust_tpu.config import ServeConfig
@@ -649,7 +649,7 @@ def cell_serve_device():
 
 
 def cell_flow_device():
-    """Silicon variant of the ``flow`` cell (perf/when_up_r11.sh): the
+    """Silicon variant of the ``flow`` cell (``--device``): the
     SAME full-sampling loadgen on the default jax backend.  Because op
     ages are logical-tick integers, the chip must reproduce the cpu
     cell's numbers EXACTLY — this cell is the cross-backend proof that

@@ -17,8 +17,8 @@ arm the probe records:
 - **loop wall** (min of ``reps``): the delta arm must not regress the
   host arm > 5% at either depth.  On the CPU tier-1 box the prefill
   round trip is a small slice of the tick, so the honest readout is
-  parity-within-noise; the silicon re-record (perf/when_up_r14.sh) is
-  where the removed dispatch-edge sync actually pays.
+  parity-within-noise; a chip run is where the removed dispatch-edge
+  sync would pay.
 - **scatter economy**: un-padded scatter length, compiled
   scatter-bucket count (steady state must stay bounded), and the
   flow/ledger counters that must not move across arms.
@@ -196,8 +196,8 @@ def run_matrix(smoke: bool = False, reps: int = 2) -> dict:
                 "host-memory traffic here, so the wall gate is "
                 "parity-within-noise (<=5%); the byte cut and the "
                 "removed dispatch-edge device read are the structural "
-                "wins, and the silicon re-record (when_up_r14.sh) is "
-                "where the hidden-sync removal shows up as overlap. "
+                "wins, and a chip run is where the hidden-sync "
+                "removal would show up as overlap. "
                 "Logical metrics are seed-deterministic and "
                 "platform-independent.",
     }

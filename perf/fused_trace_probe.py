@@ -15,8 +15,8 @@ Proves, per trace:
   BLOCKED lanes engines ``ops.rle_lanes`` / ``ops.rle_lanes_mixed``
   (per-lane expansion + the in-kernel by-order origin tables).
 
-Writes ``perf/fused_traces_r9.json``; the silicon re-record of the
-fused bench rows is armed in ``perf/when_up_r9.sh``.
+Writes ``perf/fused_traces_r9.json``; the fused bench rows' walls need
+a chip run.
 
 Run: python perf/fused_trace_probe.py [--identity-patches 1200]
      [--fuse-w 8] [--smoke]

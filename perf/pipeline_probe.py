@@ -210,10 +210,10 @@ def run_matrix(smoke: bool = False, reps: int = 2) -> dict:
         "note": "CPU run (tier-1 harness): XLA CPU saturates the cores, "
                 "so the overlap window mostly hides dispatch/sync "
                 "latency rather than buying wall — the bar here is "
-                "overlap>0 at <=5% wall cost; the silicon re-record "
-                "(perf/when_up_r12.sh) measures the real hidden device "
-                "time.  Logical metrics (ages, steps, bytes) are "
-                "seed-deterministic and platform-independent.",
+                "overlap>0 at <=5% wall cost; a chip run measures the "
+                "real hidden device time.  Logical metrics (ages, "
+                "steps, bytes) are seed-deterministic and "
+                "platform-independent.",
     }
     return out
 

@@ -9,8 +9,10 @@ stated before real multi-chip exists).
 
 Runs in its own process because the sp mesh needs
 ``xla_force_host_platform_device_count`` set before the CPU client
-exists; bench.py shells out here (the ``probe_device`` subprocess
-pattern). Prints one JSON object per row on stdout.
+exists; bench.py shells out here. CPU-only by construction: it never
+touches the chip, so it can run beside a process that holds it.  Prints
+one JSON object per row on stdout, each with this process's device
+identity.
 
     python perf/sp_bench.py [--patches 2000] [--smoke] [--skip-parity]
 """
@@ -40,6 +42,7 @@ from text_crdt_rust_tpu.ops import rle as R  # noqa: E402
 from text_crdt_rust_tpu.ops import span_arrays as SA  # noqa: E402
 from text_crdt_rust_tpu.parallel import make_mesh  # noqa: E402
 from text_crdt_rust_tpu.parallel.sp_apply import SpDoc  # noqa: E402
+from text_crdt_rust_tpu.utils.metrics import device_identity  # noqa: E402
 from text_crdt_rust_tpu.utils.testdata import (  # noqa: E402
     flatten_patches,
     load_testing_data,
@@ -189,6 +192,7 @@ def main():
     row8["note"] = ("virtual 8-device CPU mesh (no ICI): ops/s is a "
                     "host-mesh logic number; collectives_per_step is the "
                     "static ICI cost model")
+    row8["device"] = device_identity()
     print(json.dumps(row8), flush=True)
 
     row1, _, _ = run_sp(patches, want, nsp=1,
@@ -198,6 +202,7 @@ def main():
         parity_patches = patches[:parity_n]
         rle_parity(parity_patches, expected_content(parity_patches))
         row1["rle_parity"] = f"content-equal vs ops/rle on {parity_n} patches"
+    row1["device"] = device_identity()
     print(json.dumps(row1), flush=True)
 
 

@@ -17,9 +17,8 @@ only.  Per arm the probe records:
   eat the rest).
 - **loop wall** (min of ``reps``): no train depth may regress depth 1
   by > 5%.  On the CPU tier-1 box each dispatch is a cheap Python
-  call, so the honest readout is parity-within-noise; the silicon
-  re-record (perf/when_up_r16.sh) is where T-for-one dispatch
-  amortization actually pays.
+  call, so the honest readout is parity-within-noise; a chip run
+  (``--device``) is where T-for-one dispatch amortization would pay.
 - **compile economy**: distinct (T-bucket, S-bucket) train programs
   compiled — the power-of-two pad series must keep this bounded (the
   compile set is ADDITIVE: train programs + scatter programs, because
@@ -204,8 +203,8 @@ def run_matrix(smoke: bool = False, reps: int = 2) -> dict:
         "note": "CPU run (tier-1 harness): a dispatch here is a cheap "
                 "Python-to-XLA call, so the wall gate is parity-within-"
                 "noise (<=5%); the dispatch cut is the structural win "
-                "and the silicon re-record (when_up_r16.sh) is where "
-                "T-for-one launch amortization shows up as wall. "
+                "and a chip run is where T-for-one launch "
+                "amortization would show up as wall. "
                 "Logical metrics are seed-deterministic and platform-"
                 "independent; depth-4 cut < 4x ceiling because lane "
                 "residency boundaries (evict, upload, rank-table "
@@ -219,9 +218,9 @@ def main():
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--device", action="store_true",
                     help="run on the default jax backend instead of "
-                         "forcing CPU (perf/when_up_r16.sh; write to a "
-                         "separate --out so the committed CPU record "
-                         "stays the tier-1 reference)")
+                         "forcing CPU (on the chip; write to a separate "
+                         "--out so the committed CPU record stays the "
+                         "tier-1 reference)")
     ap.add_argument("--reps", type=int, default=2)
     ap.add_argument("--out", default="perf/train_r17.json")
     a = ap.parse_args()

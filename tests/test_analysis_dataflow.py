@@ -491,15 +491,13 @@ CLAIMS_TREE = {
         | good row | **measured** | `perf/real_r1.json` |
         | ghost row | **measured** | `perf/ghost_r9.json` |
         | sourceless | measured on CPU | trust me |
-        | stale watcher | pending silicon | armed in `perf/when_up_r3.sh` |
+        | speed row | not measured on the current tree | — |
 
         ## History
-        `perf/when_up_r3.sh` named in narrative is exempt by design.
+        `perf/real_r1.json` named in narrative is fine.
         """,
     "PERF.md": "see `perf/missing_probe.py`\n",
     "perf/real_r1.json": "{}",
-    "perf/when_up_r3.sh": "#!/bin/sh\n",
-    "perf/when_up_r9.sh": "#!/bin/sh\n",
 }
 
 
@@ -509,10 +507,9 @@ def test_claims_findings_name_rotted_evidence(tmp_path):
     assert {(f.path, f.line) for f in c1} == {("README.md", 6),
                                              ("PERF.md", 1)}
     c3 = the(findings, "TCR-C003")
+    # The "not measured" row (line 8) claims nothing: no finding.
     assert {f.line for f in c3} == {6, 7}
-    c2 = the(findings, "TCR-C002")
-    assert [(f.path, f.line) for f in c2] == [("README.md", 8)]
-    assert "when_up_r9" in c2[0].message  # names the current watcher
+    none_of(findings, "TCR-C002")  # the watcher check is gone
 
 
 def test_claims_clean_when_artifacts_committed(tmp_path):
@@ -523,18 +520,16 @@ def test_claims_clean_when_artifacts_committed(tmp_path):
         | claim | status | evidence |
         |---|---|---|
         | good row | **measured** | `perf/real_r1.json` |
-        | armed | pending silicon | armed in `perf/when_up_r9.sh` |
+        | speed row | not measured on the current tree | — |
         """
     tree["PERF.md"] = "see `perf/real_r1.json`\n"
     findings, _ = lint_tree(tmp_path, tree)
-    for check in ("TCR-C001", "TCR-C002", "TCR-C003"):
+    for check in ("TCR-C001", "TCR-C003"):
         none_of(findings, check)
 
 
 def test_real_repo_claims_are_consistent():
-    """The shipped README/PERF cite only committed artifacts and the
-    current recovery watcher (the first TCR-C audit fixed four stale
-    when_up references in the claims table)."""
+    """The shipped README/PERF cite only committed artifacts."""
     from text_crdt_rust_tpu.analysis.checks_claims import check_claims
 
     assert [f.format() for f in check_claims(REPO)] == []

@@ -123,3 +123,16 @@ def test_merge_rows_refuses_shape_drifted_rows(tmp_path):
     with pytest.raises(ValueError, match="device_steps"):
         merge_config_rows(p, "kevin", [drifted], "v")
     assert not os.path.exists(p)  # nothing written
+
+
+def test_make_row_stamps_device_identity():
+    """Every row names the backend of the process that produced it, so
+    a CPU row can never pass for a chip row."""
+    import bench
+
+    r = bench.make_row("cfg", "rle", 10, 1, 1.0, 10, 0, None, True)
+    assert (r["platform"], r["device_count"]) == ("cpu", 8)
+    assert r["device_kind"]
+    child = {"platform": "cpu", "device_kind": "cpu", "device_count": 1}
+    assert bench.make_row("cfg", "rle", 10, 1, 1.0, 10, 0, None, True,
+                          device=child)["device_count"] == 1

@@ -130,7 +130,7 @@ def test_flush_is_atomic(tmp_path):
 
 def test_merge_rows_replaces_cfg_key(tmp_path):
     """Single-config re-records replace that key's prior rows in place
-    (the when_up_* drop+rerun convention, without hand-editing)."""
+    (drop and re-run, without hand-editing)."""
     from bench import merge_config_rows
     p = str(tmp_path / "all.json")
     with open(p, "w") as f:

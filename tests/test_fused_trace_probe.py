@@ -10,8 +10,7 @@ The smoke calls ``identity_prefix`` IN-PROCESS at the probe's own tight
 geometry (a subprocess would re-pay the jax import; the suite's shared
 512-row geometry was measured SLOWER here — fatter interpret replays
 cost more than warm-cache builds save).  The probe's CLI and JSON
-writer are exercised by the ``slow``-tier claims check below and by
-``perf/when_up_r9.sh`` on silicon day.
+writer are exercised by the ``slow``-tier claims check below.
 """
 import importlib.util
 import json

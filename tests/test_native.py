@@ -151,3 +151,15 @@ def test_native_replays_automerge_paper():
     nat.replay_trace(a, pos, dels, ins_lens, cps)
     assert nat.to_string() == data.end_content
     assert len(nat) == len(data.end_content)
+
+
+def test_native_library_is_keyed_on_the_host_cpu(monkeypatch):
+    """The library is built with -march=native: a checkout copied to
+    another host must not find (and load) this host's build."""
+    from text_crdt_rust_tpu.native import build as NB
+
+    here = NB.lib_path()
+    monkeypatch.setattr(NB, "_host_cpu", lambda: "some other cpu")
+    assert NB.lib_path() != here
+    monkeypatch.setattr(NB, "FLAGS", NB.FLAGS[:-1])
+    assert NB.lib_path() != here

@@ -50,9 +50,10 @@ def test_committed_ledger_validates_and_covers_acceptance_floor():
     assert set(cpu_cell_names(led)) >= {"serve", "serve-lanes",
                                         "fused-trace", "sp"}
     # Headline invariants the ledger now pins: the sp ICI cost model
-    # and the blocked-lanes touched-row economy.
-    assert led["cells"]["sp"]["metrics"][
-        "collectives_per_step"]["v"] == 124
+    # (compiled HLO, so pinned with the jax it was recorded under) and
+    # the blocked-lanes touched-row economy.
+    assert (led["recorded"]["jax"], led["cells"]["sp"]["metrics"][
+        "collectives_per_step"]["v"]) == ("0.9.0", 52)
     assert led["cells"]["serve-lanes"]["metrics"][
         "touched_rows_ratio"]["v"] >= 5
 
@@ -167,3 +168,24 @@ def test_check_ledger_refuses_device_cells(tmp_path):
     # Refusal happens before any derivation, so this is in-process
     # cheap (no jax work).
     assert bench_mod.run_ledger_check(args) == 2
+
+
+def test_check_ledger_names_a_jax_version_change():
+    """Compiled-HLO metrics are the compiler's: under another jax the
+    gate asks for a re-record instead of reporting drift, and still
+    compares the logical counters exactly."""
+    led = load_ledger(LEDGER)
+    fresh = json.loads(json.dumps(led["cells"]))
+    fresh["sp"]["metrics"]["collectives_per_step"]["v"] += 72
+    ok, diffs = diff_ledger(led, fresh, jax_version=led["recorded"]["jax"])
+    assert not ok and any("sp.collectives_per_step" in d for d in diffs)
+    ok, diffs = diff_ledger(led, fresh, jax_version="0.0.1")
+    assert not ok
+    assert [d for d in diffs if d.startswith("sp:")] == [
+        f"sp: hlo metrics recorded under jax {led['recorded']['jax']}, "
+        f"installed jax 0.0.1 — re-record (python "
+        f"perf/cost_ledger_probe.py --cells sp)"]
+    assert not any("collectives_per_step" in d for d in diffs)
+    fresh["sp"]["metrics"]["steps"]["v"] += 1  # logical: still exact
+    _, diffs = diff_ledger(led, fresh, jax_version="0.0.1")
+    assert any("sp.steps" in d for d in diffs)

@@ -60,10 +60,8 @@ check families consume them:
                             drift vs the pinned ``SHAPE_CONTRACTS.json``
                             (refreshed via ``--update-pins``)
 ``checks_claims``           TCR-C001 a cited ``perf/`` artifact that
-                            does not exist, TCR-C002 a superseded
-                            ``when_up_r*.sh`` in README's claims table,
-                            TCR-C003 a "measured" claims row with no
-                            committed source
+                            does not exist, TCR-C003 a "measured"
+                            claims row with no committed source
 ==========================  ================================================
 
 CLI: ``python -m text_crdt_rust_tpu.analysis.lint`` (exit 1 with

@@ -3,27 +3,18 @@
 
 The repo's evidence discipline says a measured number is only a claim
 when its artifact is committed (README "Measured vs pending silicon",
-PERF.md cost-model sections, the ``perf/*_r*.json`` probes).  Claims
-rot structurally: a probe JSON gets superseded and renamed, a
-recovery-watcher script (``when_up_r*.sh``) gets replaced by the next
-round's, and the prose keeps citing the old name.  Nothing executes
-markdown, so no test catches it — a docs cross-check does:
+PERF.md, the ``perf/*_r*.json`` probes).  Claims rot structurally: a
+probe JSON gets superseded or renamed and the prose keeps citing the
+old name.  Nothing executes markdown, so no test catches it — a docs
+cross-check does:
 
 - **TCR-C001** — a ``perf/<file>`` reference in README.md / PERF.md
   that does not exist on disk: the cited evidence is gone (deleted,
   renamed, or never committed).
-- **TCR-C002** — inside README's "Measured vs pending silicon" claims
-  section ONLY, a reference to a superseded ``perf/when_up_r<K>.sh``
-  when a higher-round watcher exists: each round's watcher supersedes
-  the last (it replays the whole re-record chain), so a claims row
-  pointing at an old one advertises a recovery path that will not
-  re-record today's rows.  Historical narrative elsewhere (PERF.md's
-  append-only sections, README's round-by-round notes) legitimately
-  names its era's script and is exempt by design.
-- **TCR-C003** — a row of that claims table whose status column says
-  "measured" but whose row cites NO committed artifact (no existing
-  ``perf/*`` file, ``BENCH_ALL.json`` or ``COST_LEDGER.json``): a
-  measured number with no committed source.
+- **TCR-C003** — a row of README's claims table whose status column
+  says "measured" but whose row cites NO committed artifact (no
+  existing ``perf/*`` file, ``BENCH_ALL.json`` or ``COST_LEDGER.json``):
+  a measured number with no committed source.
 
 Pure project-level pass (markdown is not walked by the .py file
 iterator); temp trees without the doc files skip silently.
@@ -41,7 +32,6 @@ DOC_FILES = ("README.md", "PERF.md")
 CLAIMS_HEADING = "## Measured vs pending silicon"
 
 _PERF_REF = re.compile(r"perf/[A-Za-z0-9_\-]+\.(?:json|sh|py|log)")
-_WHEN_UP = re.compile(r"perf/when_up_r(\d+)[a-z]?\.sh")
 _ARTIFACT = re.compile(r"(perf/[A-Za-z0-9_\-]+\.(?:json|log)|"
                        r"BENCH_ALL\.json|COST_LEDGER\.json)")
 
@@ -58,22 +48,8 @@ def _claims_region(lines: List[str]) -> Optional[Tuple[int, int]]:
     return (start, len(lines)) if start is not None else None
 
 
-def _latest_when_up(root: str) -> Optional[int]:
-    perf = os.path.join(root, "perf")
-    if not os.path.isdir(perf):
-        return None
-    best = None
-    for fn in sorted(os.listdir(perf)):
-        m = re.fullmatch(r"when_up_r(\d+)[a-z]?\.sh", fn)
-        if m:
-            k = int(m.group(1))
-            best = k if best is None else max(best, k)
-    return best
-
-
 def check_claims(root: str) -> List[Finding]:
     out: List[Finding] = []
-    latest = _latest_when_up(root)
     for doc in DOC_FILES:
         path = os.path.join(root, doc)
         if not os.path.exists(path):
@@ -100,26 +76,13 @@ def check_claims(root: str) -> List[Finding]:
         start, end = region
         for i in range(start, end):
             line = lines[i]
-            # C002: superseded recovery watcher inside the claims table.
-            if latest is not None:
-                for m in _WHEN_UP.finditer(line):
-                    if int(m.group(1)) < latest:
-                        out.append(Finding(
-                            check="TCR-C002", path=doc, line=i + 1,
-                            scope="<doc>",
-                            message=f"claims row cites superseded "
-                                    f"{m.group(0)} — the current "
-                                    f"recovery watcher is "
-                                    f"perf/when_up_r{latest}.sh (each "
-                                    f"round's watcher replays the "
-                                    f"whole re-record chain); point "
-                                    f"the claim at it"))
             # C003: a "measured" row must cite a committed artifact.
             cells = [c.strip() for c in line.split("|")]
             if len(cells) < 4 or not line.lstrip().startswith("|"):
                 continue
             status = cells[2].lower()
-            if "measured" not in status or cells[1] in ("claim", "---"):
+            if ("measured" not in status or "not measured" in status
+                    or cells[1] in ("claim", "---")):
                 continue
             cited = [m.group(1) for m in _ARTIFACT.finditer(line)]
             committed = [c for c in cited

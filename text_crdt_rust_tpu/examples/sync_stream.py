@@ -14,10 +14,10 @@ Every chunk is verified against the Python oracle.
 Usage::
 
     python -m text_crdt_rust_tpu.examples.sync_stream \
-        [--docs N] [--chunks C] [--ops-per-chunk K] [--seed S] [--cpu]
+        [--docs N] [--chunks C] [--ops-per-chunk K] [--seed S]
 
-``--cpu`` runs the kernel in interpret mode on the CPU backend (no TPU
-needed) — the default everywhere but a bench box.
+The platform comes from ``JAX_PLATFORMS``: on the chip the kernel runs
+compiled, elsewhere (``JAX_PLATFORMS=cpu``) in Pallas interpret mode.
 """
 from __future__ import annotations
 
@@ -34,15 +34,11 @@ def main(argv=None) -> int:
     ap.add_argument("--ops-per-chunk", type=int, default=15,
                     help="patches per peer per chunk")
     ap.add_argument("--seed", type=int, default=7)
-    ap.add_argument("--cpu", action="store_true", default=True)
-    ap.add_argument("--tpu", dest="cpu", action="store_false",
-                    help="compile for the attached accelerator")
     args = ap.parse_args(argv)
 
-    if args.cpu:
-        import jax
+    import jax
 
-        jax.config.update("jax_platforms", "cpu")
+    interpret = jax.default_backend() != "tpu"
 
     from ..common import txn_len
     from ..models.oracle import ListCRDT
@@ -134,7 +130,7 @@ def main(argv=None) -> int:
             rkl_acc = rkl_c
         run = RLM.make_replayer_lanes_mixed(
             stacked, capacity=capacity, order_capacity=ocap,
-            chunk=16, init=state, rkl=rkl_acc, interpret=args.cpu)
+            chunk=16, init=state, rkl=rkl_acc, interpret=interpret)
         res = run()
         res.check()
         state = res.state()

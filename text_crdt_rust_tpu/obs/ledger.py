@@ -37,13 +37,13 @@ Ledger shape::
                         "tol": <relative band, 0.0 = exact>}}}}}
 
 Wall-clock data NEVER enters a cpu cell: the ledger is a logical cost
-contract, and wall histograms belong to the ``device`` cells the
-silicon re-record (``perf/when_up_r11.sh``) appends.
+contract, and wall histograms belong to the ``device`` cells that
+``perf/cost_ledger_probe.py --device`` appends on the chip.
 """
 from __future__ import annotations
 
 import json
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 LEDGER_SCHEMA_VERSION = 2  # v2 (ISSUE 16): "recovery" metric family
 #                            (journal/replay durability counters)
@@ -176,20 +176,43 @@ def diff_cell(name: str, committed: dict, fresh: dict) -> List[str]:
     return out
 
 
-def diff_ledger(committed: dict, fresh_cells: Dict[str, dict]
+def _without_hlo(cell: dict) -> dict:
+    return dict(cell, metrics={k: m for k, m in cell.get("metrics",
+                                                         {}).items()
+                               if m.get("family") != "hlo"})
+
+
+def diff_ledger(committed: dict, fresh_cells: Dict[str, dict],
+                jax_version: Optional[str] = None
                 ) -> Tuple[bool, List[str]]:
     """Compare committed cells against freshly derived ones; only cells
     present in ``fresh_cells`` are judged (the gate derives the cpu
-    cells; device cells wait for silicon).  Returns (ok, named diffs).
+    cells; device cells are recorded on the chip).  Returns (ok, named
+    diffs).
+
+    ``hlo`` metrics are what the compiler emits, so they are only
+    comparable under the jax the ledger was recorded with: when
+    ``jax_version`` differs from ``recorded.jax``, each cell's hlo
+    metrics yield one "re-record" finding instead of drift diffs (the
+    logical counters are still compared exactly).
     """
     diffs: List[str] = []
     cells = committed.get("cells", {})
+    recorded = committed.get("recorded", {}).get("jax")
+    other_jax = jax_version is not None and jax_version != recorded
     for name in sorted(fresh_cells):
         if name not in cells:
             diffs.append(f"{name}: derived a cell the committed ledger "
                          f"does not carry (re-record to adopt it)")
             continue
-        diffs.extend(diff_cell(name, cells[name], fresh_cells[name]))
+        want, got = cells[name], fresh_cells[name]
+        if other_jax and want != _without_hlo(want):
+            diffs.append(f"{name}: hlo metrics recorded under jax "
+                         f"{recorded}, installed jax {jax_version} — "
+                         f"re-record (python perf/cost_ledger_probe.py "
+                         f"--cells {name})")
+            want, got = _without_hlo(want), _without_hlo(got)
+        diffs.extend(diff_cell(name, want, got))
     return not diffs, diffs
 
 

@@ -44,4 +44,3 @@ length, scanned tile-by-tile with host-carried prefixes (SURVEY §5's
 "block-wise scans for >HBM documents"; mutation at that scale goes
 through ``rle_hbm`` or ``parallel.sp_apply``).
 """
-from . import _pallas_compat  # noqa: F401  (pltpu API aliasing)

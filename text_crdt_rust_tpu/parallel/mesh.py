@@ -150,6 +150,7 @@ def make_sharded_apply(mesh: Mesh, donate: bool = True,
             docs = shard_docs(prefill_logs(docs, ops), mesh)
         return jitted(docs, ops)
 
+    checked.jitted = jitted  # the bare program, for AOT compiles
     return checked
 
 

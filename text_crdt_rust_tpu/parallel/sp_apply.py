@@ -58,7 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..common import ROOT_ORDER
@@ -108,7 +108,7 @@ def make_sp_apply(mesh: Mesh, R: int, OTS: int):
     @partial(shard_map, mesh=mesh,
              in_specs=(spec,) * 6 + (none,) * 9,
              out_specs=(spec,) * 6 + (none, none, none),
-             check_rep=False)
+             check_vma=False)
     def replay(ordp0, lenp0, rows0, oll0, orl0, rkl0,
                kind, pos, dlen, dtgt, olop, orop, rank, ilen, start):
         idx = jnp.arange(R)

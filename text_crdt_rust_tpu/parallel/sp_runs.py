@@ -34,7 +34,7 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -69,7 +69,7 @@ def make_sp_ops(mesh: Mesh):
     none = P()
 
     @partial(shard_map, mesh=mesh, in_specs=(spec, spec),
-             out_specs=(spec, none), check_rep=False)
+             out_specs=(spec, none), check_vma=False)
     def live_prefix(ordp, lenp):
         """(per-row global live prefix [CAP], total live chars [])."""
         lv = _live_lens(ordp, lenp)
@@ -83,7 +83,7 @@ def make_sp_ops(mesh: Mesh):
         return local + carry, jnp.sum(totals)
 
     @partial(shard_map, mesh=mesh, in_specs=(spec, spec, none),
-             out_specs=(none, none), check_rep=False)
+             out_specs=(none, none), check_vma=False)
     def position_of_live_rank(ordp, lenp, rank1):
         """Live rank (1-based) -> (global row index, 1-based offset in
         that run). Exactly one shard owns the hit; psum extracts it.
@@ -113,7 +113,7 @@ def make_sp_ops(mesh: Mesh):
                 jax.lax.psum(off.astype(jnp.int32), "sp"))
 
     @partial(shard_map, mesh=mesh, in_specs=(spec, spec, none),
-             out_specs=none, check_rep=False)
+             out_specs=none, check_vma=False)
     def order_to_position(ordp, lenp, order):
         """Item order -> content position (live chars strictly before
         it); -1 if the item is a tombstone or unknown."""

@@ -340,3 +340,14 @@ def print_stats(doc, detailed: bool = False) -> None:
         for k in ("deletes_entries", "double_delete_entries", "txn_entries"):
             if k in d:
                 print(f"  {k}: {d[k]}")
+
+
+def device_identity() -> dict:
+    """The default backend of THIS process, as JAX reports it: every
+    bench row carries it, so a CPU row can never pass for a chip row."""
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs)}
