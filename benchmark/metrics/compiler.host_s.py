@@ -1,0 +1,18 @@
+"""compiler.host_s (s): the host compiler's part of set-up, the sum of
+the program's ``compiler.merge``, ``compiler.compile`` and
+``compiler.fuse`` span totals (``obs.trace.span_totals``: the whole
+process, which is one run).  A program without them reads nothing."""
+
+KINDS = ("compiler.merge", "compiler.compile", "compiler.fuse")
+
+
+def read(rec, totals=None):
+    if totals is None:
+        try:
+            from text_crdt_rust_tpu.obs.trace import span_totals
+        except ImportError:
+            return None
+        totals = span_totals()
+    if not all(k in totals for k in KINDS):
+        return None
+    return sum(totals[k]["total_s"] for k in KINDS)

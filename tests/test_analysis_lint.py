@@ -177,6 +177,21 @@ def test_unknown_trace_kind_flagged(tmp_path):
     assert f.line == 3 and "bogus.kind" in f.message
 
 
+def test_unknown_span_kind_flagged(tmp_path):
+    """The module-level ``span(kind)`` is held to the schema too."""
+    findings, _ = lint_tree(tmp_path, {"mod.py": """\
+        from text_crdt_rust_tpu.obs.trace import span
+
+        def f():
+            with span("rle.dispatch"):
+                pass
+            with span("rle.bogus"):
+                pass
+        """})
+    f = the(findings, "TCR-S001")[0]
+    assert f.line == 6 and "rle.bogus" in f.message
+
+
 def test_unknown_ledger_family_flagged(tmp_path):
     findings, _ = lint_tree(tmp_path, {"mod.py": """\
         from text_crdt_rust_tpu.obs.ledger import metric

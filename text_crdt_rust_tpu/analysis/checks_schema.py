@@ -6,7 +6,8 @@ against a declared schema, and a schema change ships with a version
 bump.  Two of those cross-checks are per-call-site:
 
 - **TCR-S001** every string-literal kind passed to ``.event(...)`` /
-  ``.span(...)`` must exist in ``obs.trace.EVENT_SCHEMA`` — an emit
+  ``.span(...)`` / ``span(...)`` must exist in
+  ``obs.trace.EVENT_SCHEMA`` — an emit
   site for an undeclared kind would raise at runtime *if* that path
   runs in tests; the lint catches it before any path runs.
 - **TCR-S002** every string-literal family passed to
@@ -67,11 +68,14 @@ def check(ctx: FileContext) -> List[Finding]:
     families = _ledger_families()
     out: List[Finding] = []
     for node in ast.walk(ctx.tree):
-        if not (isinstance(node, ast.Call)
-                and isinstance(node.func, ast.Attribute)):
+        if not isinstance(node, ast.Call):
             continue
-        attr = node.func.attr
-        if attr in ("event", "span") and node.args:
+        if isinstance(node.func, ast.Attribute):
+            emits = node.func.attr in ("event", "span")
+        else:
+            emits = (isinstance(node.func, ast.Name)
+                     and node.func.id == "span")
+        if emits and node.args:
             arg = node.args[0]
             if (isinstance(arg, ast.Constant) and isinstance(arg.value, str)
                     and arg.value not in kinds):

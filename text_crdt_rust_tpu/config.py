@@ -378,10 +378,6 @@ class ServeConfig:
     obs_dir: Optional[str] = None  # post-mortem bundle directory;
     #                            None = $TCR_TRACE_DIR or
     #                            <spool_dir>/obs
-    profile_dir: Optional[str] = None  # opt-in jax.profiler capture:
-    #                            start a device trace into this dir at
-    #                            tick 1, stop after profile_ticks
-    profile_ticks: int = 3     # ticks per jax.profiler capture window
 
     def add_args(self, ap: argparse.ArgumentParser) -> None:
         ap.add_argument("--serve-shards", type=int, default=self.num_shards)

@@ -43,22 +43,44 @@ kind                 emitted by / meaning
 ``divergence``       router/verifier: equal watermarks, unequal digests
                      (or a twin/lane bit-identity mismatch)
 ``resync.round``     session/router: anti-entropy round (wants emitted)
-``profile``          serve: jax.profiler capture started/stopped
+``compiler.merge``   replay path (``ops.batch.merge_patches``):
+                     keystroke patches merged into runs
+``compiler.compile`` ``ops.batch.compile_local_patches``: runs compiled
+                     to op tensors
+``compiler.fuse``    ``ops.batch.fuse_steps``: steps fused to ``fuse_w``
+``rle.stage``        ``ops.rle.make_replayer_rle``: op columns padded
+                     and put on the device
+``rle.build``        an ``ops.rle`` replayer's first call: trace, lower,
+                     compile (or compile-cache load), first enqueue
+``rle.dispatch``     every later call: kernel enqueue + per-group unpack
+                     (wall and thread CPU; the unpack can wait on the
+                     device)
 ``flow.*``           per-op provenance spans (obs/flow.py): emit /
                      frame / reject / buffer / ready / apply — one
                      ``(agent, seq)`` span's journey through the
                      serving loop, agent-sampled
 ===================  =======================================================
+
+The ``compiler.*``/``rle.*`` kinds and the batcher's ``tick.drain``/
+``tick.device``/``tick.barrier`` walls (``SPAN_KINDS``) are timed with
+``span``: each region is a ``jax.profiler.TraceAnnotation`` on the
+``/host:CPU`` plane of any profile (the device trace's clock), and its
+host-clock wall, the executables compiled inside it and, for
+``rle.build``/``rle.dispatch``, its thread CPU time are added to
+process-wide per-kind totals (``span_totals``), with or without a
+tracer.
 """
 from __future__ import annotations
 
+import functools
 import json
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
-TRACE_SCHEMA_VERSION = 2  # v2 (ISSUE 16): journal.segment /
+TRACE_SCHEMA_VERSION = 3  # v2: journal.segment /
 #                           journal.refuse / recovery.replay /
-#                           chaos.crash event kinds
+#                           chaos.crash event kinds; v3: the replay
+#                           path's span kinds, ``profile`` removed
 
 # kind -> required logical field names (beyond the envelope "i"/"t"/"k").
 # Extra fields are allowed — the schema pins the floor, not the ceiling.
@@ -78,7 +100,14 @@ EVENT_SCHEMA: Dict[str, Tuple[str, ...]] = {
     "codec.reject": ("err",),
     "divergence": ("doc",),
     "resync.round": ("wants",),
-    "profile": ("action",),
+    # The replay path's spans (``SPAN_KINDS``): host compiler, program
+    # build and dispatch.  They carry no logical fields.
+    "compiler.merge": (),
+    "compiler.compile": (),
+    "compiler.fuse": (),
+    "rle.stage": (),
+    "rle.build": (),
+    "rle.dispatch": (),
     # Per-op provenance spans (ISSUE 11, obs/flow.py): one op's journey
     # on the logical tick axis.  Remote spans carry (agent, seq, n);
     # local edits carry a per-doc ordinal ``lk`` until the oracle
@@ -100,6 +129,12 @@ EVENT_SCHEMA: Dict[str, Tuple[str, ...]] = {
     "flow.ready": ("doc", "agent", "seq", "n"),
     "flow.apply": ("doc", "agent", "seq", "n", "mode"),
 }
+
+#: Every kind the program times with ``span``: the replay path's, in the
+#: order the work happens, then the batcher's tick phases.
+SPAN_KINDS = ("compiler.merge", "compiler.compile", "compiler.fuse",
+              "rle.stage", "rle.build", "rle.dispatch",
+              "tick.drain", "tick.device", "tick.barrier")
 
 # The one reserved envelope key wall-clock data lives under; stripping
 # it is the whole logical projection.
@@ -134,26 +169,115 @@ def event_line(ev: dict, logical_only: bool = False) -> str:
     return json.dumps(ev, sort_keys=True, separators=(",", ":"))
 
 
+# kind -> [count, total_s, max_s, compiles, cpu_s] of every span this
+# process closed (``cpu_s`` None until a span of the kind reads it).
+_TOTALS: Dict[str, list] = {}
+
+# Executables JAX has compiled from scratch since the first span opened:
+# every backend compile, less those loaded from the persistent compile
+# cache (a cache hit is reported as a compile too).
+_COMPILES = [0]
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+def _on_jax_event(event: str, *args, **kwargs) -> None:
+    if event == _BACKEND_COMPILE:
+        _COMPILES[0] += 1
+    elif event == _CACHE_HIT:
+        _COMPILES[0] -= 1
+
+
+@functools.lru_cache(maxsize=None)
+def _jax_hooks():
+    """``TraceAnnotation``, with the compile counter listening (once)."""
+    import jax
+    from jax.profiler import TraceAnnotation
+
+    jax.monitoring.register_event_listener(_on_jax_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+    return TraceAnnotation
+
+
 class _Span:
-    """Context manager emitting ONE event at exit, with the measured
-    wall duration segregated under the ``"w"`` key."""
+    """One timed region (see ``span``).  After exit, ``t0``/``t1`` are
+    its ``perf_counter`` bounds and ``wall_s`` its wall."""
 
-    __slots__ = ("tracer", "kind", "fields", "t0")
+    __slots__ = ("tracer", "kind", "fields", "cpu", "t0", "t1", "c0",
+                 "k0", "_ann")
 
-    def __init__(self, tracer: "Tracer", kind: str, fields: dict):
+    def __init__(self, kind: str, tracer: Optional["Tracer"], cpu: bool,
+                 fields: dict):
         self.tracer = tracer
         self.kind = kind
+        self.cpu = cpu
         self.fields = fields
 
+    @property
+    def wall_s(self) -> float:
+        return self.t1 - self.t0
+
     def __enter__(self) -> "_Span":
+        self._ann = ann = _jax_hooks()(self.kind)
+        ann.__enter__()
+        self.k0 = _COMPILES[0]
+        if self.cpu:
+            self.c0 = time.thread_time()
         self.t0 = time.perf_counter()
         return self
 
-    def __exit__(self, *exc) -> bool:
-        ms = (time.perf_counter() - self.t0) * 1e3
-        self.tracer.event(self.kind, wall={"ms": round(ms, 3)},
-                          **self.fields)
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.t1 = t1 = time.perf_counter()
+        cpu = time.thread_time() - self.c0 if self.cpu else None
+        self._ann.__exit__(exc_type, exc, tb)
+        wall = t1 - self.t0
+        compiles = _COMPILES[0] - self.k0
+        tot = _TOTALS.get(self.kind)
+        if tot is None:
+            _TOTALS[self.kind] = [1, wall, wall, compiles, cpu]
+        else:
+            tot[0] += 1
+            tot[1] += wall
+            if wall > tot[2]:
+                tot[2] = wall
+            tot[3] += compiles
+            if cpu is not None:
+                tot[4] = cpu if tot[4] is None else tot[4] + cpu
+        tr = self.tracer
+        if tr is not None and tr.enabled:
+            tr.event(self.kind, wall={"ms": round(wall * 1e3, 3)},
+                     **self.fields)
         return False
+
+
+def span(kind: str, tracer: Optional["Tracer"] = None, *, cpu: bool = False,
+         **fields) -> _Span:
+    """``with span("rle.dispatch"): ...`` times one region of the
+    program three ways: a ``jax.profiler.TraceAnnotation`` named
+    ``kind`` (on the profiler's host plane, on the device trace's
+    clock, whenever a profile is being taken), the process's per-kind
+    totals (always), and -- with an enabled ``tracer`` -- the kind's
+    event at exit, ``fields`` logical and the wall under ``"w"``.
+    ``cpu=True`` also reads the calling thread's CPU time, which leaves
+    out the region's waits (on the device, say); it costs two more
+    clock reads.  ``kind`` must be in ``EVENT_SCHEMA``."""
+    return _Span(kind, tracer, cpu, fields)
+
+
+def span_totals() -> Dict[str, dict]:
+    """``{kind: {"count", "total_s", "max_s", "compiles", "cpu_s"}}``
+    over every span this process closed since the last
+    ``reset_span_totals``: ``compiles`` counts the executables JAX
+    compiled from scratch inside them (not those loaded from the
+    persistent compile cache), and ``cpu_s`` is the thread CPU time of
+    those opened with ``cpu=True`` (None if none was)."""
+    return {k: {"count": c, "total_s": t, "max_s": m, "compiles": n,
+                "cpu_s": u}
+            for k, (c, t, m, n, u) in _TOTALS.items()}
+
+
+def reset_span_totals() -> None:
+    _TOTALS.clear()
 
 
 class Tracer:
@@ -248,9 +372,10 @@ class Tracer:
         return ev
 
     def span(self, kind: str, **fields) -> _Span:
-        """``with tracer.span("tick.barrier", shard=0): ...`` — one
-        event at exit, wall duration under ``"w"``."""
-        return _Span(self, kind, fields)
+        """``with tracer.span("tick.barrier", shard=0): ...`` — the
+        module's ``span`` with this tracer: one event at exit, wall
+        duration under ``"w"``."""
+        return span(kind, self, **fields)
 
     # -- read back -----------------------------------------------------------
 

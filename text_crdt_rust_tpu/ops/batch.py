@@ -40,6 +40,7 @@ from ..common import (
     RemoteTxn,
     txn_len,
 )
+from ..obs.trace import span
 from ..utils.rle import KOrderSpan, Rle
 from ..utils.testdata import TestData, TestPatch, flatten_patches
 
@@ -316,6 +317,11 @@ def merge_patches(patches: Sequence[TestPatch]) -> List[TestPatch]:
     count; the merged stream is an execution strategy, not a workload
     reduction (the native baseline replays the unmerged stream).
     """
+    with span("compiler.merge"):
+        return _merge_runs(patches)
+
+
+def _merge_runs(patches: Sequence[TestPatch]) -> List[TestPatch]:
     out: List[TestPatch] = []
     for p in patches:
         if out:
@@ -443,9 +449,19 @@ def compile_local_patches(
     assert dmax is None or dmax >= 1, f"dmax must be >= 1, got {dmax}"
     assert fuse_w >= 1, f"fuse_w must be >= 1, got {fuse_w}"
     assert fuse_shapes in ("burst", "all"), fuse_shapes
+    with span("compiler.compile"):
+        ops, next_order = _compile_rows(list(patches), rank, lmax,
+                                        start_order, dmax, fuse_w)
+    if fuse_shapes == "all":
+        ops, _ = fuse_steps(ops, fuse_w=fuse_w, dmax=dmax)
+    return ops, next_order
+
+
+def _compile_rows(patches: List[TestPatch], rank: int, lmax: int,
+                  next_order: int, dmax: Optional[int], fuse_w: int
+                  ) -> Tuple[OpTensors, int]:
+    """The body of ``compile_local_patches``, fusion aside."""
     rows = _Rows(lmax)
-    next_order = start_order
-    patches = list(patches)
     i = 0
     while i < len(patches):
         p = patches[i]
@@ -506,10 +522,7 @@ def compile_local_patches(
             )
             next_order += len(chunk)
             off += len(chunk)
-    ops = rows.to_tensors()
-    if fuse_shapes == "all":
-        ops, _ = fuse_steps(ops, fuse_w=fuse_w, dmax=dmax)
-    return ops, next_order
+    return rows.to_tensors(), next_order
 
 
 def compile_trace(data: TestData, rank: int = 0, lmax: int = 16
@@ -757,6 +770,12 @@ def fuse_steps(ops: OpTensors, lmax: Optional[int] = None,
     orders, origins, ranks and chars are preserved column-for-column, so
     the fused stream is bit-identical in device state to the unfused
     one (the ``tests/test_rle_fused.py`` contract)."""
+    with span("compiler.fuse"):
+        return _fuse_rows(ops, lmax, fuse_w, dmax)
+
+
+def _fuse_rows(ops: OpTensors, lmax: Optional[int], fuse_w: int,
+               dmax: Optional[int]) -> Tuple[OpTensors, FuseStats]:
     kinds = np.asarray(ops.kind)
     assert kinds.ndim == 1, (
         "fuse_steps takes one unbatched [S] stream; fuse per-doc "

@@ -60,6 +60,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..common import ROOT_ORDER
+from ..obs.trace import span
 from .batch import (
     KIND_LOCAL,
     OpTensors,
@@ -587,26 +588,35 @@ def make_replayer_rle(
         # array dim); 1-D chunk blocks indexed g*(s_pad//chunk)+i are.
         return jnp.asarray(np.concatenate(cols))
 
-    staged = (staged_col(lambda o: o.pos),
-              staged_col(lambda o: o.del_len),
-              staged_col(lambda o: o.ins_len),
-              staged_col(lambda o: o.ins_order_start),
-              staged_col(lambda o: o.rows_per_step))
+    with span("rle.stage"):
+        staged = (staged_col(lambda o: o.pos),
+                  staged_col(lambda o: o.del_len),
+                  staged_col(lambda o: o.ins_len),
+                  staged_col(lambda o: o.ins_order_start),
+                  staged_col(lambda o: o.rows_per_step))
 
     jitted = _build_call(G, s_pad, batch, capacity, block_k, chunk,
                          WMAX, interpret)
+    built = False
 
     def run():
-        ol, orr, ordp, lenp, blk, rows, meta, err = jitted(*staged)
-        results = [
-            RleResult(
-                ordp=ordp[gi * capacity:(gi + 1) * capacity],
-                lenp=lenp[gi * capacity:(gi + 1) * capacity],
-                blkord=blk[gi], rows=rows[gi], meta=meta[gi],
-                ol=ol[gi, :lens[gi]], orr=orr[gi, :lens[gi]], err=err,
-                block_k=block_k, num_blocks=NB, batch=batch)
-            for gi in range(G)
-        ]
+        # The first call builds the program (trace, lower, compile or
+        # compile-cache load); every later one enqueues it.  The unpack
+        # can block behind the device once the runtime's queue is full,
+        # so the span reads thread CPU time too: the host's own work.
+        nonlocal built
+        with span("rle.dispatch" if built else "rle.build", cpu=True):
+            ol, orr, ordp, lenp, blk, rows, meta, err = jitted(*staged)
+            results = [
+                RleResult(
+                    ordp=ordp[gi * capacity:(gi + 1) * capacity],
+                    lenp=lenp[gi * capacity:(gi + 1) * capacity],
+                    blkord=blk[gi], rows=rows[gi], meta=meta[gi],
+                    ol=ol[gi, :lens[gi]], orr=orr[gi, :lens[gi]], err=err,
+                    block_k=block_k, num_blocks=NB, batch=batch)
+                for gi in range(G)
+            ]
+        built = True
         return results if grouped else results[0]
 
     return run
@@ -616,9 +626,10 @@ def make_replayer_rle(
 def _build_call(G: int, s_pad: int, batch: int, capacity: int,
                 block_k: int, chunk: int, wmax: int, interpret: bool):
     """Shape-keyed cache (the ``rle_lanes._build_call`` pattern): every
-    same-shape replay shares one traced kernel — a per-call
-    ``jax.jit(lambda ...)`` re-traces the whole interpret program each
-    time, which dominates the fixed-shape test suites."""
+    same-shape replay shares one traced kernel — a per-call ``jax.jit``
+    re-traces the whole interpret program each time, which dominates
+    the fixed-shape test suites.  The call is named ``rle_replay``, in
+    its jit and its Pallas kernel alike, so a profile names it."""
     NB = capacity // block_k
     NBLp = max(8, NB)
     blocks_per_g = s_pad // chunk
@@ -670,8 +681,13 @@ def _build_call(G: int, s_pad: int, batch: int, capacity: int,
             vmem_limit_bytes=110 * 1024 * 1024,
         ),
         interpret=interpret,
+        name="rle_replay",
     )
-    return jax.jit(lambda a, b, c, d, e: call(a, b, c, d, e))
+
+    def rle_replay(pos, dlen, ilen, start, width):
+        return call(pos, dlen, ilen, start, width)
+
+    return jax.jit(rle_replay)
 
 
 def replay_local_rle(ops, capacity: int, **kw):

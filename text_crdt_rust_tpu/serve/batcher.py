@@ -39,6 +39,7 @@ from ..common import LocalOp, RemoteDel, RemoteIns, RemoteTxn
 from ..net.session import txn_refs_known
 from ..models.sync import agent_watermarks
 from ..obs.registry import observe
+from ..obs.trace import span
 from ..ops import batch as B
 from ..ops import flat as F
 from ..ops import span_arrays as SA
@@ -785,15 +786,16 @@ class ContinuousBatcher:
         if tok["done"]:
             return
         shard = tok["shard"]
-        t0 = time.perf_counter()
+        with span("tick.barrier") as sync:
+            if tok["token"] is not None:
+                np.asarray(tok["token"])
+            else:
+                self.residency.backends[shard].barrier()
         win = 0.0
         if tok["token"] is not None:
-            win = max(0.0, t0 - max(entry["t_dispatched"],
-                                    self._last_sync_end))
-            np.asarray(tok["token"])
-        else:
-            self.residency.backends[shard].barrier()
-        stall = time.perf_counter() - t0
+            win = max(0.0, sync.t0 - max(entry["t_dispatched"],
+                                         self._last_sync_end))
+        stall = sync.wall_s
         tok["done"] = True
         self._check_guards(entry, shard)
         self._last_sync_end = time.perf_counter()
@@ -1120,99 +1122,100 @@ class ContinuousBatcher:
         tick_guards: List[dict] = []
         active_shards: set = set()
         for shard, backend in enumerate(self.residency.backends):
-            t_drain = time.perf_counter()
             lane_streams: Dict[int, B.OpTensors] = {}
             host_only_applies = 0
             shard_events = 0
             shard_steps = 0
             probed = degraded = 0
             budget = self.step_buckets[-1]
-            for doc in self.router.docs.values():
-                if doc.shard != shard or not doc.events:
-                    continue
-                if not doc.resident:
-                    continue  # restore deferred (no lane, no memory)
-                stream, applied, steps, ev_ranges = self._drain_doc(
-                    doc, budget, compile_device=doc.in_lane)
-                applied_events.extend(applied)
-                stats["events_applied"] += len(applied)
-                stats["ops_applied"] += sum(e.items for e in applied)
-                shard_events += len(applied)
-                shard_steps += steps
-                fs = None
-                if (self.fuse_steps and doc.in_lane
-                        and stream is not None):
-                    if stream.num_steps > 1:
-                        # Fuse the doc's tick stream BEFORE the
-                        # capacity probe and stacking: per-event
-                        # compilation never sees adjacent events, so
-                        # this is where typing runs / sweeps / replaces
-                        # / same-tick remote runs collapse
-                        # (bit-identical stream, fewer rows).
-                        stream, fs = B.fuse_steps(
-                            stream,
-                            fuse_w=min(self.fuse_w,
-                                       getattr(backend, "max_fuse_w",
-                                               1)))
-                    else:
-                        # Single-step streams can't fuse but ARE device
-                        # steps: count them so steps_total/ops_per_step
-                        # measure the whole run, not the fused subset.
-                        fs = B.FuseStats(steps_in=stream.num_steps,
-                                         steps_out=stream.num_steps)
-                scheduled = False
-                if doc.in_lane and stream is not None:
-                    # Lane-capacity probe AFTER the oracle applied (the
-                    # oracle is truth): overflow degrades to host-only,
-                    # frees the lane, skips the device — never asserts.
-                    # Backends define their own unit (chars for flat,
-                    # run rows + split headroom for the blocked lanes).
-                    probed += 1
-                    if backend.tick_fits(doc.lane, doc.oracle, stream):
-                        scheduled = True
-                        if self.step_trace is not None:
-                            self.step_trace(doc.doc_id, stream)
-                        lane_streams[doc.lane] = stream
-                        stats["steps"] += stream.num_steps
-                        if fs is not None:
-                            # Count fusion only for streams that WILL
-                            # run as device steps: a probe failure
-                            # degrades to host-only, and its rows must
-                            # not inflate the exported device-step
-                            # counters.
-                            self.fuse_stats.merge(fs)
-                            if tr is not None and fs.rows_saved > 0:
-                                tr.event("tick.fuse", doc=doc.doc_id,
-                                         steps_in=fs.steps_in,
-                                         steps_out=fs.steps_out)
-                            observe(self.counters, "ops_per_step",
-                                    fs.reduction_x)
-                            observe(self.counters, "fused_rows_saved",
-                                    fs.rows_saved)
-                        if self.recorder is not None:
-                            self.recorder.record_stream(doc.doc_id, {
-                                "tick": tick_no,
-                                "num_steps": int(stream.num_steps),
-                                "steps_prefuse": (fs.steps_in if fs
-                                                  else int(stream.num_steps)),
-                            })
-                    else:
-                        degraded += 1
-                        self.residency.degrade(
-                            doc, f"lane capacity overflow: {doc.oracle.n} "
-                                 f"rows / {doc.oracle.get_next_order()} "
-                                 f"orders vs {backend.capacity}/"
-                                 f"{backend.order_capacity}")
-                elif not doc.in_lane and applied:
-                    host_only_applies += 1
-                if self.flow is not None and applied:
-                    # Terminal flow.apply per span, stamped AFTER the
-                    # capacity probe so the mode is truthful: a probe
-                    # failure means the oracle applied but no device
-                    # step ran — "host", exactly like host-only docs.
-                    self._flow_applies(doc, applied, ev_ranges,
-                                       fs if scheduled else None,
-                                       scheduled)
+            with span("tick.drain") as drain:
+                for doc in self.router.docs.values():
+                    if doc.shard != shard or not doc.events:
+                        continue
+                    if not doc.resident:
+                        continue  # restore deferred (no lane, no memory)
+                    stream, applied, steps, ev_ranges = self._drain_doc(
+                        doc, budget, compile_device=doc.in_lane)
+                    applied_events.extend(applied)
+                    stats["events_applied"] += len(applied)
+                    stats["ops_applied"] += sum(e.items for e in applied)
+                    shard_events += len(applied)
+                    shard_steps += steps
+                    fs = None
+                    if (self.fuse_steps and doc.in_lane
+                            and stream is not None):
+                        if stream.num_steps > 1:
+                            # Fuse the doc's tick stream BEFORE the
+                            # capacity probe and stacking: per-event
+                            # compilation never sees adjacent events, so
+                            # this is where typing runs / sweeps / replaces
+                            # / same-tick remote runs collapse
+                            # (bit-identical stream, fewer rows).
+                            stream, fs = B.fuse_steps(
+                                stream,
+                                fuse_w=min(self.fuse_w,
+                                           getattr(backend, "max_fuse_w",
+                                                   1)))
+                        else:
+                            # Single-step streams can't fuse but ARE device
+                            # steps: count them so steps_total/ops_per_step
+                            # measure the whole run, not the fused subset.
+                            fs = B.FuseStats(steps_in=stream.num_steps,
+                                             steps_out=stream.num_steps)
+                    scheduled = False
+                    if doc.in_lane and stream is not None:
+                        # Lane-capacity probe AFTER the oracle applied (the
+                        # oracle is truth): overflow degrades to host-only,
+                        # frees the lane, skips the device — never asserts.
+                        # Backends define their own unit (chars for flat,
+                        # run rows + split headroom for the blocked lanes).
+                        probed += 1
+                        if backend.tick_fits(doc.lane, doc.oracle, stream):
+                            scheduled = True
+                            if self.step_trace is not None:
+                                self.step_trace(doc.doc_id, stream)
+                            lane_streams[doc.lane] = stream
+                            stats["steps"] += stream.num_steps
+                            if fs is not None:
+                                # Count fusion only for streams that WILL
+                                # run as device steps: a probe failure
+                                # degrades to host-only, and its rows must
+                                # not inflate the exported device-step
+                                # counters.
+                                self.fuse_stats.merge(fs)
+                                if tr is not None and fs.rows_saved > 0:
+                                    tr.event("tick.fuse", doc=doc.doc_id,
+                                             steps_in=fs.steps_in,
+                                             steps_out=fs.steps_out)
+                                observe(self.counters, "ops_per_step",
+                                        fs.reduction_x)
+                                observe(self.counters, "fused_rows_saved",
+                                        fs.rows_saved)
+                            if self.recorder is not None:
+                                self.recorder.record_stream(doc.doc_id, {
+                                    "tick": tick_no,
+                                    "num_steps": int(stream.num_steps),
+                                    "steps_prefuse": (
+                                        fs.steps_in if fs
+                                        else int(stream.num_steps)),
+                                })
+                        else:
+                            degraded += 1
+                            self.residency.degrade(
+                                doc, f"lane capacity overflow: {doc.oracle.n} "
+                                     f"rows / {doc.oracle.get_next_order()} "
+                                     f"orders vs {backend.capacity}/"
+                                     f"{backend.order_capacity}")
+                    elif not doc.in_lane and applied:
+                        host_only_applies += 1
+                    if self.flow is not None and applied:
+                        # Terminal flow.apply per span, stamped AFTER the
+                        # capacity probe so the mode is truthful: a probe
+                        # failure means the oracle applied but no device
+                        # step ran — "host", exactly like host-only docs.
+                        self._flow_applies(doc, applied, ev_ranges,
+                                           fs if scheduled else None,
+                                           scheduled)
 
             if tr is not None and (shard_events or shard_steps):
                 # Drain wall = the whole host-side doc loop (oracle
@@ -1221,8 +1224,7 @@ class ContinuousBatcher:
                 # in-flight device step (analyze.py overlap reads it).
                 tr.event("tick.drain", shard=shard, events=shard_events,
                          steps=shard_steps,
-                         wall={"ms": round((time.perf_counter()
-                                            - t_drain) * 1e3, 3)})
+                         wall={"ms": round(drain.wall_s * 1e3, 3)})
             if tr is not None and probed:
                 tr.event("tick.capacity", shard=shard, probed=probed,
                          degraded=degraded)
@@ -1255,9 +1257,9 @@ class ContinuousBatcher:
                 # sync (wall-only; the logical stream cannot tell).
                 if getattr(backend, "dispatch_reads_device", True):
                     self._sync_shard_inflight(shard)
-                t_dev = time.perf_counter()
-                backend.apply(stacked)
-                disp_ms = (time.perf_counter() - t_dev) * 1e3
+                with span("tick.device") as dispatch:
+                    backend.apply(stacked)
+                disp_ms = dispatch.wall_s * 1e3
                 if self.sanitize_pipeline:
                     # Dispatch-edge fingerprint: these exact array
                     # objects are what the in-flight device step may

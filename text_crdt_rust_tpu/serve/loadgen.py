@@ -853,9 +853,6 @@ def parse_args(argv=None) -> argparse.Namespace:
                          "crc32(name) %% mod == 0 get end-to-end "
                          "flow.* span events (1 = every span, the "
                          "conservation-audit mode; 0 = off)")
-    ap.add_argument("--profile-dir", default=None,
-                    help="opt-in jax.profiler capture directory "
-                         "(ticks 1..profile_ticks)")
     ap.add_argument("--journal-dir", default=None,
                     help="write-ahead op journal directory (ISSUE 16): "
                          "every admitted input is logged before it can "
@@ -909,7 +906,6 @@ def loadgen_from_args(a: argparse.Namespace) -> ServeLoadGen:
                       trace=not a.no_trace, trace_path=a.trace_path,
                       trace_rotate_bytes=a.trace_rotate_bytes,
                       flow_sample_mod=a.flow_sample_mod,
-                      profile_dir=a.profile_dir,
                       journal_dir=a.journal_dir,
                       journal_fsync_ticks=a.journal_fsync_ticks)
     return ServeLoadGen(docs=a.docs, agents_per_doc=a.agents, ticks=a.ticks,

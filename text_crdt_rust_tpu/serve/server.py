@@ -121,7 +121,6 @@ class DocServer:
                                          sanitize_pipeline=cfg.sanitize_pipeline,
                                          train_ticks=cfg.train_ticks)
         self.tick_no = 0
-        self._profiling = False
 
     # -- traffic surface ----------------------------------------------------
 
@@ -154,7 +153,6 @@ class DocServer:
     def tick(self) -> Dict[str, float]:
         self.tick_no += 1
         self.router.set_tick(self.tick_no)
-        self._profile_hook()
         stats = self.batcher.tick(self.tick_no)
         if self.journal is not None:
             # The tick boundary is the journal's fsync point AND the
@@ -172,55 +170,13 @@ class DocServer:
         self.batcher.flush_pipeline()
 
     def close_obs(self) -> None:
-        """Finalize observability at end of run: stop a still-running
-        profiler capture (a run shorter than ``profile_ticks`` would
-        otherwise never write its trace — and leave the process-global
-        profiler running into the next server) and close the trace
-        file. Idempotent; drivers (loadgen, probes) call it on
-        teardown."""
+        """Finalize observability at end of run: sync the pipeline and
+        close the journal and the trace file. Idempotent; drivers
+        (loadgen, probes) call it on teardown."""
         self.flush_pipeline()
-        if self._profiling:
-            import jax
-
-            try:
-                jax.profiler.stop_trace()
-                self.tracer.event("profile", action="stop",
-                                  dir=self.cfg.profile_dir)
-            except Exception as e:
-                self.counters.incr("profile_errors")
-                self.tracer.event("profile", action="error",
-                                  err=f"{type(e).__name__}: {e}")
-            self._profiling = False
         if self.journal is not None:
             self.journal.close()
         self.tracer.close()
-
-    def _profile_hook(self) -> None:
-        """Opt-in ``jax.profiler`` capture (ISSUE 8 device hooks): trace
-        ticks 1..profile_ticks into ``cfg.profile_dir``. Failure to
-        start a profiler (unsupported backend) is counted, never
-        raised — profiling must not take the serving loop down."""
-        if not self.cfg.profile_dir:
-            return
-        import jax
-
-        try:
-            if self.tick_no == 1 and not self._profiling:
-                jax.profiler.start_trace(self.cfg.profile_dir)
-                self._profiling = True
-                self.tracer.event("profile", action="start",
-                                  dir=self.cfg.profile_dir)
-            elif (self._profiling
-                  and self.tick_no > self.cfg.profile_ticks):
-                jax.profiler.stop_trace()
-                self._profiling = False
-                self.tracer.event("profile", action="stop",
-                                  dir=self.cfg.profile_dir)
-        except Exception as e:
-            self._profiling = False
-            self.counters.incr("profile_errors")
-            self.tracer.event("profile", action="error",
-                              err=f"{type(e).__name__}: {e}")
 
     def recover(self) -> Dict[str, int]:
         """Rebuild a crashed server by re-executing its input log
