@@ -8,14 +8,20 @@ shrunken debug node sizes (`range_tree/mod.rs:29-39`). Streams are
 compiled through ``merge_patches`` (the production path) AND raw, so both
 run-granular and per-keystroke ops hit the kernel.
 """
+import collections
 import random
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core as jcore
 
 from text_crdt_rust_tpu.ops import batch as B
 from text_crdt_rust_tpu.ops import flat as F
 from text_crdt_rust_tpu.ops import rle as R
+from text_crdt_rust_tpu.ops import rle_hbm as RH
+from text_crdt_rust_tpu.ops import rle_mixed as RM
 from text_crdt_rust_tpu.ops import span_arrays as SA
 from text_crdt_rust_tpu.utils.testdata import (
     TestPatch,
@@ -217,3 +223,122 @@ class TestVsNativeEngine:
         from text_crdt_rust_tpu.ops import span_arrays as SA2
         assert SA2.doc_spans(doc) == nd.doc_spans()
         assert SA2.to_string(doc) == nd.to_string()
+
+
+def _prepends(n):
+    """``n`` discontiguous two-char runs (each typed at position 0)."""
+    return [TestPatch(0, 0, "ab") for _ in range(n)]
+
+
+def _slot0_boundary_insert():
+    # Twelve runs at block_k 8 fill two slots; an insert at the end of
+    # slot 0 takes its raw successor from row 0 of slot 1.
+    prefix = _prepends(12)
+    ops, _ = B.compile_local_patches(prefix, lmax=2, dmax=None)
+    res = R.replay_local_rle(ops, capacity=64, batch=8, block_k=8,
+                             chunk=128, interpret=True)
+    rows = np.asarray(res.rows)[:, 0]
+    assert int(np.asarray(res.meta)[0, 0]) >= 2 and 0 < rows[0] < 12
+    return prefix + [TestPatch(2 * int(rows[0]), 0, "Q")]
+
+
+ROW_READ_EDGES = {
+    # i_r + 1 == r0 and l + 1 == nlog: the successor is the root.
+    "append_after_last_row": lambda: [
+        TestPatch(0, 0, "abc"), TestPatch(0, 0, "x"), TestPatch(4, 0, "Z")],
+    "successor_in_next_slot": _slot0_boundary_insert,
+    "insert_into_empty_doc": lambda: [TestPatch(0, 0, "abc")],
+    # abcd|XY|efgh, then one delete from inside the first run to inside
+    # the last: both boundary partials in one block (npart == 2).
+    "delete_two_partials": lambda: [
+        TestPatch(0, 0, "abcdefgh"), TestPatch(4, 0, "XY"),
+        TestPatch(2, 6, "")],
+    # Seven runs fill slot 0 (block_k 8); the append splits it and lands
+    # in the freshly split slot 1.
+    "insert_after_split": lambda: _prepends(7) + [TestPatch(14, 0, "Z")],
+}
+
+
+class TestRowReads:
+    """The kernel reads control scalars and single rows as one-row loads
+    (``_rle_kernel``'s ``row_at``/``block_rows``); ``rle_hbm`` keeps the
+    masked-reduction default of the shared block math, so it is the
+    reference for every edge of those reads."""
+
+    @pytest.mark.parametrize("case", sorted(ROW_READ_EDGES))
+    def test_row_read_edges_vs_hbm(self, case):
+        patches = ROW_READ_EDGES[case]()
+        lmax = max(len(p.ins_content) for p in patches)
+        ops, _ = B.compile_local_patches(patches, lmax=lmax, dmax=None)
+        kw = dict(capacity=64, batch=8, block_k=8, chunk=128,
+                  interpret=True)
+        got = R.replay_local_rle(ops, **kw)
+        want = RH.replay_local_rle_hbm(ops, **kw)
+        np.testing.assert_array_equal(R.expand_runs(got),
+                                      R.expand_runs(want))
+        for a, b in ((got.ol, want.ol), (got.orr, want.orr)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        ref = ref_doc(patches, 64)
+        assert SA.to_string(R.rle_to_flat(ops, got)) == SA.to_string(ref)
+
+
+def _kernel_reductions(fn, args):
+    """``(primitive, operand shape) -> count`` of the reductions in the
+    kernel jaxpr of the one ``pallas_call`` that ``fn`` traces to."""
+    counts = collections.Counter()
+
+    def subs(eqn):
+        for v in eqn.params.values():
+            for x in v if isinstance(v, (list, tuple)) else (v,):
+                if isinstance(x, jcore.ClosedJaxpr):
+                    yield x.jaxpr
+                elif isinstance(x, jcore.Jaxpr):
+                    yield x
+
+    def walk(jaxpr, in_kernel):
+        for eqn in jaxpr.eqns:
+            if in_kernel and eqn.primitive.name.startswith("reduce_"):
+                shape = tuple(eqn.invars[0].aval.shape)
+                counts[(eqn.primitive.name, shape)] += 1
+            for sub in subs(eqn):
+                walk(sub, in_kernel or eqn.primitive.name == "pallas_call")
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, False)
+    return counts
+
+
+class TestWholePlaneReductions:
+    """The mechanism's engagement record: a reduction over a whole
+    ``[K, B]`` block or ``[NBLp, B]`` slot table is left only where it
+    computes an aggregate (the descent, the ``i_r`` count, the delete's
+    cover counts, ``split``'s upper live count).  Before the one-row
+    loads the benchmark's geometry traced 20 table and 20 block sums."""
+
+    def test_rle_kernel_at_benchmark_geometry(self):
+        # capacity 20,992 runs of K=128: 164 slots, B=512 lanes.
+        fn = R._build_call(1, 8192, 512, 20992, 128, 1024, 8, False)
+        c = _kernel_reductions(fn, [jax.ShapeDtypeStruct(
+            (8192,), jnp.int32)] * 5)
+        # slot_of_live_rank twice on each path.
+        assert c[("reduce_sum", (164, 512))] <= 4
+        # insert: the i_r count; delete: tot, npart; split's liv_hi in
+        # each path's copy.
+        assert c[("reduce_sum", (128, 512))] <= 5
+        assert c[("reduce_min", (128, 512))] == 1      # i1
+        assert c[("reduce_max", (128, 512))] == 1      # i2
+
+    @pytest.mark.parametrize("engine", ["rle_hbm", "rle_mixed"])
+    def test_other_engines_keep_masked_default(self, engine):
+        # Their kernels pass no row reader: every row of the shared
+        # block math is still a masked reduction, as many as before.
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+        if engine == "rle_hbm":
+            fn = RH._build_call(1, 1024, 8, 256, 8, 128, 8, True, False)
+            args = [i32(1024)] * 5
+            want = {("reduce_sum", (8, 8)): 29, ("reduce_sum", (64, 8)): 8}
+        else:
+            fn = RM._build_mixed_call(128, 8, 256, 8, 128, 8, False, True)
+            args = [i32(128)] * 9 + [i32(8, RM.LANES)] * 3
+            want = {("reduce_sum", (8, 8)): 72, ("reduce_sum", (32, 8)): 59}
+        c = _kernel_reductions(fn, args)
+        assert {k: c[k] for k in want} == want

@@ -36,7 +36,10 @@ instead of 259,778 steps over 524k rows:
   round-2 engines off the pure-prepend worst case (`benches/yjs.rs:51-62`);
 - documents batch in the lane dimension (identical-stream lanes), and
   divergent doc GROUPS ride a leading grid dimension exactly like
-  ``ops.blocked_hbm`` (config-3 ragged corpus shape).
+  ``ops.blocked_hbm`` (config-3 ragged corpus shape);
+- control scalars and single rows are read as one-row loads
+  (``ref[pl.ds(row, 1), :]``): only the descent and the delete's cover
+  counts reduce over a whole plane.
 
 Origins a local insert discovers (`doc.rs:447-453`) are emitted per op:
 ``origin_left`` of the run head, with the rest of the run chained
@@ -91,20 +94,38 @@ def _row_scalar(arr2d, r, idx_k) -> jax.Array:
     return jnp.max(jnp.sum(jnp.where(idx_k == r, arr2d, 0), axis=0))
 
 
-def _locate_run(bo, bl, idx_k, r0, local):
+def _masked_run_rows(bo, bl, idx_k):
+    """The default row-read primitive of the shared block math
+    (``_locate_run``, ``_delete_block_math``; ``fused_splice_rows``
+    takes its ``shift`` the same way).  ``rows(cum, lv)`` returns
+    ``read(r) -> (o, l, before)``: row ``r``'s signed order and length
+    in the block as loaded, and the live chars before it in the block
+    (``cum - lv`` at ``r``).  Each read is a masked reduction over the
+    whole [K, B] value, which any block value allows; ``_rle_kernel``
+    passes one-row loads of its refs instead (``block_rows``)."""
+
+    def rows(cum, lv):
+        def read(r):
+            return (_row_scalar(bo, r, idx_k), _row_scalar(bl, r, idx_k),
+                    _row_scalar(cum, r, idx_k) - _row_scalar(lv, r, idx_k))
+        return read
+    return rows
+
+
+def _locate_run(bo, bl, idx_k, r0, local, rows=None):
     """Find the run containing live char #``local`` (1-based) in a block:
     returns ``(i_r, o_r, l_r, off)`` — row index, ±(order+1), length, and
     the 1-based char offset within the run. The hit is a live run by
-    construction (tombstone rows don't advance the live cumsum)."""
+    construction (tombstone rows don't advance the live cumsum).
+    ``rows`` is the caller's row-read primitive (``_masked_run_rows``
+    when None)."""
     lv = jnp.where(bo > 0, bl, 0)
     cum = _cumsum_rows(lv)
     i_r = jnp.max(jnp.sum(
         ((cum < local) & (idx_k < r0)).astype(jnp.int32), axis=0))
-    o_r = _row_scalar(bo, i_r, idx_k)
-    l_r = _row_scalar(bl, i_r, idx_k)
-    off = local - (_row_scalar(cum, i_r, idx_k)
-                   - _row_scalar(lv, i_r, idx_k))
-    return i_r, o_r, l_r, off
+    rows = rows or _masked_run_rows(bo, bl, idx_k)
+    o_r, l_r, before = rows(cum, lv)(i_r)
+    return i_r, o_r, l_r, local - before
 
 
 def fused_splice_rows(bo, bl, idx, p, i_r, o_r, l_r, off, il, st, w,
@@ -211,7 +232,8 @@ def _split_piece_aux(aux, idx_k, i_p, amt, w1, w2, so0, s_off, e_off,
     return tuple(out)
 
 
-def _delete_block_math(bo, bl, idx_k, K, base, p, rem, aux=None):
+def _delete_block_math(bo, bl, idx_k, K, base, p, rem, aux=None,
+                       rows=None):
     """One delete iteration over one block (`mutations.rs:520-570`): flip
     fully-covered runs, split at most the two boundary runs. Returns
     ``(no, nl, added_rows, covered)``; caller walks blocks while
@@ -222,13 +244,19 @@ def _delete_block_math(bo, bl, idx_k, K, base, p, rem, aux=None):
     cache); split pieces inherit their run's origin-right/rank, and a
     non-first piece's head chains to its own predecessor char (the
     `span.rs:24-28` implicit chain survives splits). Returns the
-    transformed aux as a 5th element when given."""
+    transformed aux as a 5th element when given.
 
-    def apply_partial(active, i_p, cs, ce, bo, bl, aux):
-        o = _row_scalar(bo, i_p, idx_k)
-        ln = _row_scalar(bl, i_p, idx_k)
-        cs_i = _row_scalar(cs, i_p, idx_k)
-        ce_i = _row_scalar(ce, i_p, idx_k)
+    ``rows`` is the caller's row-read primitive (``_masked_run_rows``
+    when None).  Both boundary rows are read as loaded: a partial row
+    is never flipped, and ``i1 < i2`` keeps its place through ``i2``'s
+    split."""
+
+    def apply_partial(active, i_p, bo, bl, aux):
+        o, ln, live_before = read(i_p)
+        lv_i = jnp.where(o > 0, ln, 0)
+        before_i = base + live_before
+        cs_i = jnp.clip(p - before_i, 0, lv_i)
+        ce_i = jnp.clip(p + rem - before_i, 0, lv_i)
         cov_i = ce_i - cs_i
         has_head = (cs_i > 0) & active
         has_tail = (ce_i < ln) & active
@@ -271,11 +299,12 @@ def _delete_block_math(bo, bl, idx_k, K, base, p, rem, aux=None):
     npart = jnp.max(jnp.sum(part.astype(jnp.int32), axis=0))
     i1 = jnp.max(jnp.min(jnp.where(part, idx_k, K), axis=0))
     i2 = jnp.max(jnp.max(jnp.where(part, idx_k, -1), axis=0))
+    read = (rows or _masked_run_rows(bo, bl, idx_k))(cum, lv)
 
     bo = jnp.where(full, -bo, bo)
     # Higher-index boundary first so i1's row index stays valid.
-    bo, bl, a2, aux = apply_partial(npart >= 1, i2, cs, ce, bo, bl, aux)
-    bo, bl, a1, aux = apply_partial(npart == 2, i1, cs, ce, bo, bl, aux)
+    bo, bl, a2, aux = apply_partial(npart >= 1, i2, bo, bl, aux)
+    bo, bl, a1, aux = apply_partial(npart == 2, i1, bo, bl, aux)
     if aux is None:
         return bo, bl, a1 + a2, tot
     return bo, bl, a1 + a2, tot, aux
@@ -290,6 +319,7 @@ def _rle_kernel(
                                                 #   state — halves VMEM)
     blk_out, rows_out, meta_out, err_ref,       # tables + flags
     blkord, rws, liv, cumliv, meta,             # persistent scratch
+    cumbuf,                                     # [K,B] staged cum plane
     *, K: int, NB: int, NBL: int, CHUNK: int, WMAX: int,
 ):
     B = ordp.shape[1]
@@ -318,11 +348,33 @@ def _rle_kernel(
         cumliv[:] = jnp.zeros_like(cumliv)
         meta[0] = 1  # blocks in use (logical slots == physical blocks)
 
-    def slot_scalar(tbl, l):
-        return _lane_scalar(jnp.where(idx_l == l, tbl[:], 0))
+    def row_at(ref, r):
+        """Row ``r`` of a lane-replicated VMEM plane, as one scalar: a
+        one-row load, not a masked reduction over the plane."""
+        return _lane_scalar(ref[pl.ds(r, 1), :])
+
+    def block_rows(b):
+        """The shared block math's row-read primitive for physical block
+        ``b`` (the ``_masked_run_rows`` contract): stored rows are
+        one-row loads of ``ordp``/``lenp``, read before the block is
+        stored back; ``cum`` is staged once in ``cumbuf`` and a row's
+        own live length follows from its order and length.  Rows are
+        clamped into the block: every read outside it is masked by the
+        caller."""
+
+        def rows(cum, lv):
+            cumbuf[:] = cum
+
+            def read(r):
+                r = jnp.clip(r, 0, K - 1)
+                o = row_at(ordp, b * K + r)
+                ln = row_at(lenp, b * K + r)
+                return o, ln, row_at(cumbuf, r) - jnp.where(o > 0, ln, 0)
+            return read
+        return rows
 
     def live_before_slot(l):
-        return slot_scalar(cumliv, l) - slot_scalar(liv, l)
+        return row_at(cumliv, l) - row_at(liv, l)
 
     def slot_of_live_rank(rank1):
         """Smallest logical slot whose cumulative live-char count reaches
@@ -355,8 +407,8 @@ def _rle_kernel(
 
         @pl.when(nlog < NB)
         def _do():
-            b = slot_scalar(blkord, l)
-            r = slot_scalar(rws, l)
+            b = row_at(blkord, l)
+            r = row_at(rws, l)
             keep = r // 2
             mv = r - keep
             nb = nlog  # fresh physical block id
@@ -364,7 +416,7 @@ def _rle_kernel(
             bl = lenp[pl.ds(b * K, K), :]
             liv_hi = _lane_scalar(jnp.where(
                 (idx_k >= keep) & (idx_k < r) & (bo > 0), bl, 0))
-            liv_lo = slot_scalar(liv, l) - liv_hi
+            liv_lo = row_at(liv, l) - liv_hi
 
             up_o = _shift_rows_up(bo, keep, K)
             up_l = _shift_rows_up(bl, keep, K)
@@ -393,7 +445,7 @@ def _rle_kernel(
 
     def find_insert_slot(p):
         l = jnp.where(p == 0, 0, slot_of_live_rank(p))
-        return l, slot_scalar(rws, l)
+        return l, row_at(rws, l)
 
     def do_insert(k, p, il, st, w):
         """Insert an ``il``-char run (or, fused, ``w`` runs of stride
@@ -408,28 +460,30 @@ def _rle_kernel(
             split(l)
 
         l, r0 = find_insert_slot(p)
-        b = slot_scalar(blkord, l)
+        b = row_at(blkord, l)
         base = live_before_slot(l)
         local = p - base
         bo = ordp[pl.ds(b * K, K), :]
         bl = lenp[pl.ds(b * K, K), :]
-        i_r, o_r, l_r, off = _locate_run(bo, bl, idx_k, r0, local)
+        i_r, o_r, l_r, off = _locate_run(bo, bl, idx_k, r0, local,
+                                         block_rows(b))
         no, nl, amt, _mrg, is_split = _insert_splice(
             bo, bl, idx_k, p, i_r, o_r, l_r, off, il, st, w, WMAX)
 
         left = jnp.where(p == 0, root_u,
                          ((o_r - 1) + (off - 1)).astype(jnp.uint32))
         # Raw successor (`doc.rs:452`: tombstones not skipped); read from
-        # the PRE-splice block.
-        nxt_in_blk = _row_scalar(bo, i_r + 1, idx_k)  # 0 past the last row
+        # the PRE-splice block, before it is stored back below.  Row
+        # i_r+1 is the successor only below r0, and the next slot's row
+        # 0 only while that slot exists: the selects below mask the rest.
+        nxt_in_blk = row_at(ordp, b * K + jnp.minimum(i_r + 1, K - 1))
         nlog = meta[0]
-        b2 = slot_scalar(blkord, jnp.minimum(l + 1, NBL - 1))
-        nxt_slot_o = jnp.max(jnp.sum(jnp.where(
-            idx_k == 0, ordp[pl.ds(b2 * K, K), :], 0), axis=0))
+        b2 = row_at(blkord, jnp.minimum(l + 1, NBL - 1))
+        nxt_slot_o = row_at(ordp, b2 * K)
         succ_signed = jnp.where(
             i_r + 1 < r0, nxt_in_blk,
             jnp.where(l + 1 < nlog, nxt_slot_o, 0))
-        first_o = _row_scalar(bo, 0, idx_k)  # p == 0: the raw doc head
+        first_o = row_at(ordp, b * K)  # p == 0: the raw doc head
         succ_p0 = jnp.where(r0 > 0, first_o, 0)
         succ = jnp.where(p == 0, succ_p0,
                          jnp.where(is_split, o_r + off, succ_signed))
@@ -454,17 +508,17 @@ def _rle_kernel(
             rem, iters = carry
             l = slot_of_live_rank(p + 1)
 
-            @pl.when(slot_scalar(rws, l) + 2 > K)
+            @pl.when(row_at(rws, l) + 2 > K)
             def _():
                 split(l)
 
             l = slot_of_live_rank(p + 1)
-            b = slot_scalar(blkord, l)
+            b = row_at(blkord, l)
             base = live_before_slot(l)
             bo = ordp[pl.ds(b * K, K), :]
             bl = lenp[pl.ds(b * K, K), :]
             no, nl, added, tot = _delete_block_math(
-                bo, bl, idx_k, K, base, p, rem)
+                bo, bl, idx_k, K, base, p, rem, rows=block_rows(b))
             ordp[pl.ds(b * K, K), :] = no
             lenp[pl.ds(b * K, K), :] = nl
             rws[pl.ds(l, 1), :] = rws[pl.ds(l, 1), :] + added
@@ -676,6 +730,7 @@ def _build_call(G: int, s_pad: int, batch: int, capacity: int,
             pltpu.VMEM((NBLp, batch), jnp.int32),       # liv
             pltpu.VMEM((NBLp, batch), jnp.int32),       # cumliv
             pltpu.SMEM((2,), jnp.int32),                # meta
+            pltpu.VMEM((block_k, batch), jnp.int32),    # cumbuf
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=110 * 1024 * 1024,
