@@ -7,7 +7,8 @@ its host-clock wall (with the executables JAX compiled inside it and,
 when asked, its thread CPU time) to ``span_totals()`` whether or not a
 tracer is given, and, with an enabled tracer, emits its kind's event
 with the wall under ``"w"``.  The replay path (``merge_patches`` ->
-``compile_local_patches`` -> ``fuse_steps`` -> ``make_replayer_rle``)
+``compile_local_patches`` -> ``fuse_steps`` -> ``make_replayer_rle``),
+the remote path (``compile_remote_txns`` -> ``make_replayer_rle_mixed``)
 and the batcher's tick phases are timed with it.
 """
 import glob
@@ -141,10 +142,28 @@ def test_spans_count_what_jax_compiled_inside_them():
 def test_span_kinds_are_declared_and_reset_clears():
     assert all(k in EVENT_SCHEMA for k in SPAN_KINDS)
     assert {"tick.drain", "tick.device", "tick.barrier"} <= set(SPAN_KINDS)
+    assert {"compiler.remote", "compiler.prefill"} <= set(SPAN_KINDS)
     with span("rle.stage"):
         pass
     reset_span_totals()
     assert span_totals() == {}
+
+
+def _host_spans(log_dir):
+    """{kind: [(start_ns, end_ns)]} of the ``SPAN_KINDS`` events on the
+    ``/host:CPU`` plane of the one profile under ``log_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    pd = ProfileData.from_file(path)
+    (host,) = [p for p in pd.planes if p.name == "/host:CPU"]
+    spans = {}
+    for line in host.lines:
+        for e in line.events:
+            if e.name in SPAN_KINDS:
+                spans.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns))
+    return spans
 
 
 def _tiny_replay(calls: int):
@@ -171,7 +190,6 @@ def test_replay_spans_land_on_the_profilers_host_plane(tmp_path):
     ``/host:CPU``, as many times as ``span_totals`` counts it: one
     build, then a dispatch for every later call."""
     import jax
-    from jax.profiler import ProfileData
 
     calls = 3
     jax.profiler.start_trace(str(tmp_path))
@@ -192,25 +210,57 @@ def test_replay_spans_land_on_the_profilers_host_plane(tmp_path):
     assert len(first) == len("the slow brown fox") + 6
     assert all(np.array_equal(expand_runs(r), first) for r in results)
 
-    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
-                        recursive=True)
-    pd = ProfileData.from_file(path)
-    (host,) = [p for p in pd.planes if p.name == "/host:CPU"]
-    seen = {}
-    spans = {}
-    for line in host.lines:
-        for e in line.events:
-            if e.name in SPAN_KINDS:
-                seen[e.name] = seen.get(e.name, 0) + 1
-                spans.setdefault(e.name, []).append(
-                    (e.start_ns, e.start_ns + e.duration_ns))
-    assert seen == got
+    spans = _host_spans(tmp_path)
+    assert {k: len(v) for k, v in spans.items()} == got
     # On one clock: the host compiler ran before the build, the build
     # before every dispatch.
     assert max(b for _, b in spans["compiler.fuse"]) \
         <= min(a for a, _ in spans["rle.build"])
     assert max(b for _, b in spans["rle.build"]) \
         <= min(a for a, _ in spans["rle.dispatch"])
+
+
+def test_remote_spans_land_on_the_profilers_host_plane(tmp_path):
+    """The remote path at a tiny size: the remote compiler (timed by the
+    caller that builds the program; a bare ``compile_remote_txns``, as
+    the serve path calls it per txn, is no span), the tables' prefill,
+    the staging, one build and a dispatch for every later call, on
+    ``/host:CPU`` in that order, as many as ``span_totals`` counts."""
+    import jax
+
+    from text_crdt_rust_tpu.common import RemoteId, RemoteIns, RemoteTxn
+    from text_crdt_rust_tpu.ops import batch as B
+    from text_crdt_rust_tpu.ops import rle_mixed as RM
+
+    root = RemoteId("ROOT", 0xFFFFFFFF)
+    txns = [RemoteTxn(id=RemoteId(n, 0), parents=[],
+                      ops=[RemoteIns(root, root, t)])
+            for n, t in [("amy", "aa"), ("zed", "zz")]]
+    B.compile_remote_txns(txns, B.AgentTable(["amy", "zed"]), lmax=4)
+    assert counts() == {}
+    calls = 3
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        table = B.AgentTable(["amy", "zed"])
+        with span("compiler.remote"):
+            ops, _ = B.compile_remote_txns(txns, table, lmax=4)
+        run = RM.make_replayer_rle_mixed(ops, capacity=64, batch=8,
+                                         block_k=8, chunk=8, interpret=True)
+        results = [run() for _ in range(calls)]
+        jax.block_until_ready(results[-1].ordp)
+    finally:
+        jax.profiler.stop_trace()
+    got = counts()
+    assert got == {"compiler.remote": 1, "compiler.prefill": 1,
+                   "rle.stage": 1, "rle.build": 1, "rle.dispatch": calls - 1}
+    assert all(r.integrate_counts() == (2, results[0].integrate_counts()[1])
+               for r in results)
+    spans = _host_spans(tmp_path)
+    assert {k: len(v) for k, v in spans.items()} == got
+    order = ("compiler.remote", "compiler.prefill", "rle.stage",
+             "rle.build", "rle.dispatch")
+    for a, b in zip(order, order[1:]):
+        assert max(e for _, e in spans[a]) <= min(s for s, _ in spans[b])
 
 
 def test_serve_tick_phases_are_spans():

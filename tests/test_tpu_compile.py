@@ -9,6 +9,9 @@ no chip time.  Geometries are the ones the chip path launches
 
 - ``ops.rle._build_call`` at the north-star shape (``chip_smoke``'s
   replay phase, ``bench.py`` northstar);
+- ``ops.rle_mixed._build_mixed_call`` at the merge cell's shape
+  (``benchmark/configs/merge-crdtbench-b24-n6000.json``: B=128, CAP
+  46,208, K 128, chunk 1024), which VMEM bounds at 128 lanes;
 - ``ops.rle_lanes_mixed._build_blocked_call`` at the serve lanes
   geometry (B=128, CAP 512, K 32, OCAP 1536, chunk 128);
 - ``ops.flat`` 's tick-train program at one serve train bucket;
@@ -71,6 +74,20 @@ def test_northstar_rle_kernel_compiles(one_chip):
     arg = jax.ShapeDtypeStruct((G * s_pad,), jnp.int32, sharding=one_chip)
     compiled = jitted.lower(*[arg] * 5).compile()
     assert _kernel_in(compiled)
+    out_bytes = compiled.memory_analysis().output_size_in_bytes
+    assert out_bytes >= 2 * cap * batch * 4
+
+
+def test_merge_rle_mixed_kernel_compiles(one_chip):
+    from text_crdt_rust_tpu.ops import rle_mixed as RM
+
+    s_pad, batch, cap, k, chunk, ot = 27648, 128, 46208, 128, 1024, 1088
+    jitted = RM._build_mixed_call(s_pad, batch, cap, k, chunk, ot, False,
+                                  True)
+    col = jax.ShapeDtypeStruct((s_pad,), jnp.int32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((ot, 128), jnp.int32, sharding=one_chip)
+    compiled = jitted.lower(*[col] * 9, *[table] * 3).compile()
+    assert "rle_mixed_replay" in compiled.as_text()
     out_bytes = compiled.memory_analysis().output_size_in_bytes
     assert out_bytes >= 2 * cap * batch * 4
 

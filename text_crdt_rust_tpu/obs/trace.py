@@ -48,10 +48,20 @@ kind                 emitted by / meaning
 ``compiler.compile`` ``ops.batch.compile_local_patches``: runs compiled
                      to op tensors
 ``compiler.fuse``    ``ops.batch.fuse_steps``: steps fused to ``fuse_w``
-``rle.stage``        ``ops.rle.make_replayer_rle``: op columns padded
-                     and put on the device
-``rle.build``        an ``ops.rle`` replayer's first call: trace, lower,
-                     compile (or compile-cache load), first enqueue
+``compiler.remote``  a whole remote stream compiled to op tensors for
+                     a replay program (``compile_remote_txns``, timed by
+                     the caller that builds the program, as the
+                     benchmark's merge driver does: the serve path's
+                     per-txn calls are not spans)
+``compiler.prefill`` ``ops.rle_mixed.make_replayer_rle_mixed``: the
+                     by-order logs prefilled (``prefill_logs``) and packed
+                     into the kernel's origin and rank tables
+``rle.stage``        ``ops.rle.make_replayer_rle`` /
+                     ``ops.rle_mixed.make_replayer_rle_mixed``: op
+                     columns padded and put on the device
+``rle.build``        an ``ops.rle`` or ``ops.rle_mixed`` replayer's first
+                     call: trace, lower, compile (or compile-cache load),
+                     first enqueue
 ``rle.dispatch``     every later call: kernel enqueue + per-group unpack
                      (wall and thread CPU; the unpack can wait on the
                      device)
@@ -77,10 +87,11 @@ import json
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
-TRACE_SCHEMA_VERSION = 3  # v2: journal.segment /
+TRACE_SCHEMA_VERSION = 4  # v2: journal.segment /
 #                           journal.refuse / recovery.replay /
 #                           chaos.crash event kinds; v3: the replay
-#                           path's span kinds, ``profile`` removed
+#                           path's span kinds, ``profile`` removed;
+#                           v4: the remote path's compiler spans
 
 # kind -> required logical field names (beyond the envelope "i"/"t"/"k").
 # Extra fields are allowed — the schema pins the floor, not the ceiling.
@@ -105,6 +116,8 @@ EVENT_SCHEMA: Dict[str, Tuple[str, ...]] = {
     "compiler.merge": (),
     "compiler.compile": (),
     "compiler.fuse": (),
+    "compiler.remote": (),
+    "compiler.prefill": (),
     "rle.stage": (),
     "rle.build": (),
     "rle.dispatch": (),
@@ -133,6 +146,7 @@ EVENT_SCHEMA: Dict[str, Tuple[str, ...]] = {
 #: Every kind the program times with ``span``: the replay path's, in the
 #: order the work happens, then the batcher's tick phases.
 SPAN_KINDS = ("compiler.merge", "compiler.compile", "compiler.fuse",
+              "compiler.remote", "compiler.prefill",
               "rle.stage", "rle.build", "rle.dispatch",
               "tick.drain", "tick.device", "tick.barrier")
 

@@ -20,7 +20,7 @@ What the remote paths add on top of ``ops.rle``'s block grid:
   Maintained per insert; a block split moves rows and deliberately
   leaves entries stale — lookups verify containment against the hinted
   block (runs make that a range test, not an equality test) and fall
-  back to ONE vectorized full-plane search, then self-heal.
+  back to ONE walk over the blocks in use, then self-heal.
 - **by-order origin/rank tables** (``oll/orl/rkl``) prefilled host-side
   (`batch.prefill_logs`), updated in-kernel by local inserts — the YATA
   scan reads per-ORDER origins, which the prefilled implicit chain
@@ -33,12 +33,13 @@ What the remote paths add on top of ``ops.rle``'s block grid:
   inside it), shrinking the scan by the run factor.
 - **one-pass remote delete**: runs are disjoint ORDER intervals, so a
   target range ``[t, t+dlen)`` fully covers every run it touches except
-  at most the two holding its endpoints — one plane-wide flip of the
-  full covers (block live counts updated off one plane cumsum) plus
-  <= 2 by-order endpoint fix-ups (3-way splits).  Already-dead covered
-  runs count toward the idempotency total without flipping (idempotent
-  concurrent deletes, `double_delete.rs:6-9`; excess counting stays
-  host-side per SURVEY).  Any ``dlen`` in one step.
+  at most the two holding its endpoints — one walk over the blocks in
+  use flips the full covers (each block's flipped chars off its slot's
+  live count) plus <= 2 by-order endpoint fix-ups (3-way splits).
+  Already-dead covered runs count toward the idempotency total without
+  flipping (idempotent concurrent deletes, `double_delete.rs:6-9`;
+  excess counting stays host-side per SURVEY).  Any ``dlen`` in one
+  step.
 
 Same lane batching as ``ops.rle`` (all docs replay one shared stream),
 same ``RleResult`` / ``rle_to_flat`` result surface.
@@ -55,6 +56,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs.trace import span
 from .batch import (
     KIND_LOCAL,
     KIND_REMOTE_DEL,
@@ -130,7 +132,15 @@ def _insert_splice_raw(bo, bl, idx_k, c, i_r, o_r, l_r, off, il, st,
 
 
 class RleMixedResult(RleResult):
-    """``RleResult`` + the order-index error flag (err row 2)."""
+    """``RleResult`` + the order-index error flag (err row 2) and the
+    integrate counters (``meta`` rows 1 and 2, see ``integrate_counts``)."""
+
+    def integrate_counts(self, lane: int = 0):
+        """(remote inserts, those whose fast scan flagged and ran the exact
+        serial walk) of one lane's pass: every remote insert runs the exact
+        walk when the replayer was built with ``fast_integrate=False``."""
+        meta = np.asarray(self.meta)
+        return int(meta[1, lane]), int(meta[2, lane])
 
     def check(self) -> None:
         super().check()
@@ -149,10 +159,10 @@ def _mixed_rle_kernel(
     ordp, lenp,                                 # [CAP, B] run planes (OUT
                                                 #   blocks as working state)
     blk_out, rows_out, meta_out, err_ref,       # tables + flags
-    blkord, rws, liv, raw, cumliv, cumraw,      # VMEM scratch (cum* =
+    rws, liv, raw, cumliv, cumraw,              # VMEM scratch (cum* =
     ordblk, oll, orl,                           #   incremental inclusive
-    olp, orp, rkp, lpp,                         #   prefixes; per-run YATA
-    meta,                                       #   aux planes; SMEM scratch
+    olp, orp, rkp,                              #   prefixes; per-run YATA
+    meta, phys, logi,                           #   aux planes; SMEM scratch
     *, K: int, NB: int, NBL: int, CHUNK: int, OT: int,
     FAST: bool = True,
 ):
@@ -162,21 +172,43 @@ def _mixed_rle_kernel(
     last = pl.num_programs(0) - 1
     idx_k = lax.broadcasted_iota(jnp.int32, (K, B), 0)
     idx_l = lax.broadcasted_iota(jnp.int32, (NBL, B), 0)
-    idx_cap = lax.broadcasted_iota(jnp.int32, (CAP, B), 0)
     lane = lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
     lane2 = lax.broadcasted_iota(jnp.int32, (2, LANES), 1)
     row2 = lax.broadcasted_iota(jnp.int32, (2, LANES), 0)
     root_i = jnp.int32(-1)  # ROOT_ORDER as i32
     root_u = jnp.uint32(0xFFFFFFFF)
 
+    # The [CAP, B] planes are only ever touched one K-row block at a
+    # time: a whole-plane expression would put CAP x B temporaries in
+    # VMEM beside the planes themselves, and that, not the planes,
+    # is what bounds the capacity a pass can hold.
+    def blk(plane, b):
+        return plane[pl.ds(b * K, K), :]
+
     ol_ref[:] = jnp.zeros_like(ol_ref)
     or_ref[:] = jnp.zeros_like(or_ref)
 
     @pl.when(i == 0)
     def _init():
-        ordp[:] = jnp.zeros_like(ordp)
-        lenp[:] = jnp.zeros_like(lenp)
-        blkord[:] = jnp.zeros_like(blkord)
+        zero = jnp.zeros((K, B), jnp.int32)
+
+        # Per-run YATA aux planes (the vectorized conflict scan's
+        # gather-free cache): origin-left / origin-right / author rank
+        # of each run's HEAD char.  Maintained through every splice;
+        # split pieces inherit or/rank and chain ol to their
+        # predecessor (`span.rs:24-28` implicit chain).
+        @pl.loop(0, NB)
+        def _(b):
+            for plane in (ordp, lenp, olp, orp, rkp):
+                plane[pl.ds(b * K, K), :] = zero
+
+        # Block maps in SMEM, logical slot <-> physical block, read as
+        # scalars wherever a descent or a block walk needs one.
+        @pl.loop(0, NBL)
+        def _(l):
+            phys[l] = 0
+            logi[l] = 0
+
         rws[:] = jnp.zeros_like(rws)
         liv[:] = jnp.zeros_like(liv)
         raw[:] = jnp.zeros_like(raw)
@@ -186,17 +218,9 @@ def _mixed_rle_kernel(
         err_ref[:] = jnp.zeros_like(err_ref)
         oll[:] = oll_in[:]
         orl[:] = orl_in[:]
-        # Per-run YATA aux planes (the vectorized conflict scan's
-        # gather-free cache): origin-left / origin-right / author rank
-        # of each run's HEAD char, plus the logical position of each
-        # row's block (the doc-order sort key).  Maintained through
-        # every splice; split pieces inherit or/rank and chain ol to
-        # their predecessor (`span.rs:24-28` implicit chain).
-        olp[:] = jnp.zeros_like(olp)
-        orp[:] = jnp.zeros_like(orp)
-        rkp[:] = jnp.zeros_like(rkp)
-        lpp[:] = jnp.zeros_like(lpp)
         meta[0] = 1  # logical blocks in use
+        meta[2] = 0  # remote inserts
+        meta[3] = 0  # ... of which ran the exact serial walk
 
     # ---- by-order tables (order o lives at [o // 128, o % 128]) ---------
 
@@ -252,12 +276,6 @@ def _mixed_rle_kernel(
         return jnp.minimum(
             jnp.max(jnp.sum(hit.astype(jnp.int32), axis=0)), nlog - 1)
 
-    def logical_of_physical(b):
-        """Slot holding physical block ``b`` (small NBL scan)."""
-        nlog = meta[0]
-        hit = (blkord[:] == b) & (idx_l < nlog)
-        return jnp.max(jnp.where(hit, idx_l, 0))
-
     def split(l):
         """Leaf split (`mutations.rs:623-669`): move the top half of slot
         ``l``'s rows to a fresh physical block spliced into the logical
@@ -272,7 +290,7 @@ def _mixed_rle_kernel(
 
         @pl.when(nlog < NB)
         def _do():
-            b = slot_scalar(blkord, l)
+            b = phys[l]
             r = slot_scalar(rws, l)
             keep = r // 2
             mv = r - keep
@@ -302,27 +320,25 @@ def _mixed_rle_kernel(
                 ap[pl.ds(b * K, K), :] = jnp.where(keep_mask, ax, 0)
             # Logical positions: blocks after slot l shift one slot
             # down; the moved-out top half (new physical block nb)
-            # lands at slot l + 1.  (Unallocated blocks' rows hold
-            # 0, never > l, so the shift cannot touch them.)
-            #
-            # LOAD-BEARING: unlike olp/orp/rkp above, lpp rows of STALE
-            # slots (>= rws, the moved-out top half of block b and the
-            # unused tail of nb) are deliberately NOT zeroed. lpp is
-            # keyed by PHYSICAL row, whole-block: when a later insert
-            # validates one of those rows (rws grows back into them),
-            # the row must already hold its block's logical slot — the
-            # fast-integrate window keys (`integrate_fast`: key =
-            # lpp * K + row) read lpp for every valid row without a
-            # per-row freshness check. Zeroing stale rows here would
-            # make a later-validated row in block b/nb inherit slot 0
-            # and silently corrupt the scan-window bounds.
-            lpp[:] = jnp.where(lpp[:] > l, lpp[:] + 1, lpp[:])
-            lpp[:] = jnp.where(idx_cap // K == nb, l + 1, lpp[:])
+            # lands at slot l + 1.
+            @pl.loop(0, nlog)
+            def _(p):
+                lp = logi[p]
+                logi[p] = jnp.where(lp > l, lp + 1, lp)
+
+            logi[nb] = l + 1
+
+            @pl.loop(0, NBL - l - 2)
+            def _(j):
+                s_ = NBL - 1 - j
+                phys[s_] = phys[s_ - 1]
+
+            phys[l + 1] = nb
 
             # cum prefixes shift with the tables; slot l+1 inherits the
             # old inclusive prefix of l (correct), slot l loses the
             # moved-out top half (see ops.rle split).
-            for tbl in (blkord, rws, liv, raw, cumliv, cumraw):
+            for tbl in (rws, liv, raw, cumliv, cumraw):
                 shifted = _shift_rows(tbl[:], 1, 1)
                 tbl[:] = jnp.where(idx_l <= l, tbl[:], shifted)
             rws[pl.ds(l, 1), :] = jnp.broadcast_to(keep, (1, B))
@@ -330,7 +346,6 @@ def _mixed_rle_kernel(
             raw[pl.ds(l, 1), :] = jnp.broadcast_to(raw_lo, (1, B))
             cumliv[pl.ds(l, 1), :] = cumliv[pl.ds(l, 1), :] - liv_hi
             cumraw[pl.ds(l, 1), :] = cumraw[pl.ds(l, 1), :] - raw_hi
-            blkord[pl.ds(l + 1, 1), :] = jnp.broadcast_to(nb, (1, B))
             rws[pl.ds(l + 1, 1), :] = jnp.broadcast_to(mv, (1, B))
             liv[pl.ds(l + 1, 1), :] = jnp.broadcast_to(liv_hi, (1, B))
             raw[pl.ds(l + 1, 1), :] = jnp.broadcast_to(raw_hi, (1, B))
@@ -352,7 +367,7 @@ def _mixed_rle_kernel(
     def locate_order(o):
         """(physical block, row) of the run containing order ``o``.
         ``ordblk`` is a HINT — splits leave it stale; verify, fall back to
-        one vectorized full-plane search, self-heal the entry.
+        one walk over the blocks in use, self-heal the entry.
 
         Callers may pass the ROOT sentinel (-1) from a discarded
         ``jnp.where`` branch (both branches evaluate): the lookup then
@@ -361,20 +376,31 @@ def _mixed_rle_kernel(
         oc = jnp.maximum(o, 0)
         bh = jnp.clip(tab_read(ordblk, oc), 0, NB - 1)
         f, row = find_in_block(bh, oc)
+        # As ``integrate_entry``: the walk is predicated and its answer
+        # passed through SMEM, not returned from a lax.cond branch.
+        meta[4] = bh * K + row
 
-        def fallback():
-            so = jnp.abs(ordp[:]) - 1
-            hit = (ordp[:] != 0) & (so <= oc) & (oc < so + lenp[:])
-            g = jnp.max(jnp.min(jnp.where(hit, idx_cap, CAP - 1), axis=0))
-            ok = _lane_scalar(hit.astype(jnp.int32)) > 0
+        @pl.when(~f)
+        def _fallback():
+            def walk(b, g):
+                bo = blk(ordp, b)
+                so = jnp.abs(bo) - 1
+                hit = (bo != 0) & (so <= oc) & (oc < so + blk(lenp, b))
+                return jnp.minimum(g, jnp.min(
+                    jnp.where(hit, b * K + idx_k, CAP), axis=0,
+                    keepdims=True))
+
+            g = jnp.max(lax.fori_loop(0, meta[0], walk,
+                                      jnp.full((1, B), CAP, jnp.int32)))
+            ok = g < CAP
 
             @pl.when(~ok & (o >= 0))
             def _missing():
                 err_ref[2:3, :] = jnp.ones((1, B), jnp.int32)
 
-            return g // K, g % K
+            meta[4] = jnp.where(ok, g, CAP - 1)
 
-        b, row = lax.cond(f, lambda: (bh, row), fallback)
+        b, row = meta[4] // K, meta[4] % K
 
         @pl.when(o >= 0)
         def _heal():
@@ -385,7 +411,7 @@ def _mixed_rle_kernel(
     def pos_of_order(o):
         """RAW document position of the char with order ``o``."""
         b, row = locate_order(o)
-        l = logical_of_physical(b)
+        l = logi[b]
         bo = ordp[pl.ds(b * K, K), :]
         bl = lenp[pl.ds(b * K, K), :]
         raw_before = _lane_scalar(jnp.where(idx_k < row, bl, 0))
@@ -461,7 +487,7 @@ def _mixed_rle_kernel(
             split(l)
 
         l, r0 = find_insert_slot(p)
-        b = slot_scalar(blkord, l)
+        b = phys[l]
         base = sum_before_slot(LIV, l)
         local = p - base
         bo = ordp[pl.ds(b * K, K), :]
@@ -476,7 +502,7 @@ def _mixed_rle_kernel(
         # the PRE-splice block.
         nxt_in_blk = _row_scalar(bo, i_r + 1, idx_k)  # 0 past the last row
         nlog = meta[0]
-        b2 = slot_scalar(blkord, jnp.minimum(l + 1, NBL - 1))
+        b2 = phys[jnp.minimum(l + 1, NBL - 1)]
         nxt_slot_o = jnp.max(jnp.sum(jnp.where(
             idx_k == 0, ordp[pl.ds(b2 * K, K), :], 0), axis=0))
         succ_signed = jnp.where(
@@ -517,7 +543,7 @@ def _mixed_rle_kernel(
                 split(l)
 
             l = slot_of_cum(LIV, p + 1)
-            b = slot_scalar(blkord, l)
+            b = phys[l]
             base = sum_before_slot(LIV, l)
             bo = ordp[pl.ds(b * K, K), :]
             bl = lenp[pl.ds(b * K, K), :]
@@ -548,7 +574,7 @@ def _mixed_rle_kernel(
         """``run_at_raw`` + the run's (logical slot, physical block,
         row): everything the fast scan's window bounds need."""
         l = slot_of_cum(RAW, c + 1)
-        b = slot_scalar(blkord, l)
+        b = phys[l]
         r0 = slot_scalar(rws, l)
         local = c - sum_before_slot(RAW, l)
         bo = ordp[pl.ds(b * K, K), :]
@@ -564,10 +590,10 @@ def _mixed_rle_kernel(
     BIGK = NBL * K + K  # past any valid doc-order key
 
     def integrate_fast(cursor0, my_rank, o_left, o_right):
-        """Vectorized YATA conflict scan: ONE classification pass over
-        all run rows plus three masked reductions replace the serial
-        run-walk (whose per-op cost grows with the document and
-        dominated the config-4 storm).
+        """Vectorized YATA conflict scan: ONE classification walk over
+        the run rows of the window's blocks, in document order, replaces
+        the serial run-walk (whose per-op cost grows with the document
+        and dominated the config-4 storm).
 
         Sound when every run in the scan window is either a direct
         SIBLING (head ``origin_left`` == the op's — order equality, so
@@ -585,6 +611,12 @@ def _mixed_rle_kernel(
           kll = last lower-ranked sibling before kfb
           kss = first higher-ranked different-o_right sibling after kll
           cursor = kss if it exists else kfb (else the o_right bound)
+
+        The walk visits the window's logical slots in order, so keys
+        rise from block to block and the three reductions fold: a slot
+        with a lower-ranked sibling restarts ``kss`` after it, and once
+        ``kfb`` is found later slots only add to ``flag``.  Lanes fold
+        apart ([1, B] carries) and meet once, after the walk.
         """
         n = total_of(RAW)
         tpos = jnp.where(o_right == root_i, n, pos_of_order(o_right))
@@ -594,30 +626,53 @@ def _mixed_rle_kernel(
         lT, bT, iT, o_rT, l_rT, offT = run_at2(tpos)
         key_hi = jnp.where(tpos >= n, BIGK,
                            lT * K + iT + jnp.where(offT == 0, 0, 1))
-        key = lpp[:] * K + idx_cap % K
-        valid = ordp[:] != 0
-        W = valid & (key > key_lo) & (key < key_hi)
-        h = jnp.abs(ordp[:]) - 1
-        S = W & (olp[:] == o_left)
-        # Chained piece whose predecessor char (order h-1) is literally
-        # the previous row's last char: olc = own head position, a
-        # plain advance.  Row 0 of a block cannot verify adjacency
-        # (its predecessor row lives in another block) -> not safe.
-        e_prev = pltpu.roll(h + lenp[:] - 1, 1, axis=0)
-        rib = idx_cap % K
-        chain = (W & ~S & (h > 0) & (olp[:] == h - 1)
-                 & (rib > 0) & (e_prev == h - 1))
-        bad = (W & ~S & ~chain) | (S & ((rkp[:] == my_rank)
-                                        | (orp[:] == -2)))
-        gt_r = rkp[:] > my_rank
-        sgo = S & gt_r & (orp[:] == o_right)
-        sgn = S & gt_r & (orp[:] != o_right)
-        slt = S & ~gt_r
-        kfb = jnp.min(jnp.where(sgo, key, BIGK))
-        kll = jnp.max(jnp.where(slt & (key < kfb), key, -1))
-        kss = jnp.min(jnp.where(sgn & (key > kll) & (key < kfb), key,
-                                BIGK))
-        flag = jnp.max(jnp.where(bad, 1, 0)) > 0
+        l_end = jnp.where(tpos >= n, meta[0] - 1, lT)
+
+        def classify(l, carry):
+            kfb, kll, kss, bad_any = carry
+            b = phys[l]
+            bo = blk(ordp, b)
+            ao, ar, ak = blk(olp, b), blk(orp, b), blk(rkp, b)
+            key = l * K + idx_k
+            W = (bo != 0) & (key > key_lo) & (key < key_hi)
+            h = jnp.abs(bo) - 1
+            S = W & (ao == o_left)
+            # Chained piece whose predecessor char (order h-1) is
+            # literally the previous row's last char: olc = own head
+            # position, a plain advance.  Row 0 of a block cannot verify
+            # adjacency (its predecessor row lives in another block) ->
+            # not safe.
+            e_prev = pltpu.roll(h + blk(lenp, b) - 1, 1, axis=0)
+            chain = (W & ~S & (h > 0) & (ao == h - 1)
+                     & (idx_k > 0) & (e_prev == h - 1))
+            bad = (W & ~S & ~chain) | (S & ((ak == my_rank) | (ar == -2)))
+            gt_r = ak > my_rank
+            sgo = S & gt_r & (ar == o_right)
+            sgn = S & gt_r & (ar != o_right)
+            slt = S & ~gt_r
+
+            def first(m):
+                return jnp.min(jnp.where(m, key, BIGK), axis=0,
+                               keepdims=True)
+
+            kfb_b = first(sgo)
+            cap = jnp.where(kfb < BIGK, -1, kfb_b)
+            kll_b = jnp.max(jnp.where(slt & (key < cap), key, -1), axis=0,
+                            keepdims=True)
+            kss_b = first(sgn & (key > kll_b) & (key < cap))
+            return (jnp.minimum(kfb, kfb_b),
+                    jnp.where(kll_b >= 0, kll_b, kll),
+                    jnp.where(kll_b >= 0, kss_b, jnp.minimum(kss, kss_b)),
+                    jnp.maximum(bad_any, jnp.max(
+                        bad.astype(jnp.int32), axis=0, keepdims=True)))
+
+        big = jnp.full((1, B), BIGK, jnp.int32)
+        kfb, kll, kss, bad_any = lax.fori_loop(
+            l0, l_end + 1, classify,
+            (big, jnp.full((1, B), -1, jnp.int32), big,
+             jnp.zeros((1, B), jnp.int32)))
+        kfb, kll, kss = jnp.min(kfb), jnp.max(kll), jnp.min(kss)
+        flag = jnp.max(bad_any) > 0
 
         # Mid-run window start: the char AT cursor0 chains to the char
         # at cursor0 - 1 == the op's origin_left char, so it is always
@@ -648,7 +703,7 @@ def _mixed_rle_kernel(
         # raw position (one block read).
         l_w = jnp.clip(kwin // K, 0, NBL - 1)
         i_w = kwin % K
-        b_w = slot_scalar(blkord, l_w)
+        b_w = phys[l_w]
         bl_w = lenp[pl.ds(b_w * K, K), :]
         hp_w = sum_before_slot(RAW, l_w) + _lane_scalar(
             jnp.where(idx_k < i_w, bl_w, 0))
@@ -658,7 +713,9 @@ def _mixed_rle_kernel(
 
     def integrate_entry(my_rank, o_left, o_right):
         cursor0 = cursor_after(o_left)
+        meta[2] = meta[2] + 1
         if not FAST:
+            meta[3] = meta[3] + 1
             return integrate_cursor(cursor0, my_rank, o_left, o_right)
         c_fast, flag = integrate_fast(cursor0, my_rank, o_left, o_right)
         # Branch via pl.when + an SMEM cell, not lax.cond: a cond whose
@@ -669,6 +726,7 @@ def _mixed_rle_kernel(
 
         @pl.when(flag)
         def _exact():
+            meta[3] = meta[3] + 1
             meta[1] = integrate_cursor(cursor0, my_rank, o_left, o_right)
 
         return meta[1]
@@ -732,7 +790,7 @@ def _mixed_rle_kernel(
             split(l)
 
         l = jnp.where(c == 0, 0, slot_of_cum(RAW, c))
-        b = slot_scalar(blkord, l)
+        b = phys[l]
         r0 = slot_scalar(rws, l)
         local = c - sum_before_slot(RAW, l)
         bo = ordp[pl.ds(b * K, K), :]
@@ -760,7 +818,7 @@ def _mixed_rle_kernel(
         LIVE and PARTIALLY covered — full covers were flipped by the
         caller's plane pass, dead runs are idempotent retires."""
         b, row = locate_order(o)
-        l = logical_of_physical(b)
+        l = logi[b]
 
         def run_facts():
             bo = ordp[pl.ds(b * K, K), :]
@@ -782,7 +840,7 @@ def _mixed_rle_kernel(
         @pl.when(partial)
         def _fix():
             b2, row2 = locate_order(o)  # split may have moved the run
-            l2 = logical_of_physical(b2)
+            l2 = logi[b2]
             bo = ordp[pl.ds(b2 * K, K), :]
             bl = lenp[pl.ds(b2 * K, K), :]
             o_r = _row_scalar(bo, row2, idx_k)
@@ -837,36 +895,33 @@ def _mixed_rle_kernel(
         order lookup, and count covered DEAD runs toward the
         idempotency total (`double_delete.rs:6-9`).  Any ``dlen`` in
         one step — no dmax pre-chunking."""
-        bo = ordp[:]
-        bl = lenp[:]
-        so = jnp.abs(bo) - 1
-        occ = bo != 0
-        cs = jnp.clip(t - so, 0, bl)
-        ce = jnp.clip(t + dlen - so, 0, bl)
-        cov = jnp.where(occ, ce - cs, 0)
-        tot = jnp.max(jnp.sum(cov, axis=0))
+        liv0 = liv[:]
+
+        def flip(b, tot):
+            # Flip the block's full covers; its slot's live count drops
+            # by the flipped chars (raw counts are unchanged by
+            # tombstoning).
+            bo = blk(ordp, b)
+            bl = blk(lenp, b)
+            so = jnp.abs(bo) - 1
+            cs = jnp.clip(t - so, 0, bl)
+            ce = jnp.clip(t + dlen - so, 0, bl)
+            cov = jnp.where(bo != 0, ce - cs, 0)
+            full = (bo > 0) & (cov > 0) & (cov == bl)
+            ordp[pl.ds(b * K, K), :] = jnp.where(full, -bo, bo)
+            l = logi[b]
+            liv[pl.ds(l, 1), :] = liv[pl.ds(l, 1), :] - jnp.sum(
+                jnp.where(full, bl, 0), axis=0, keepdims=True)
+            return tot + jnp.sum(cov, axis=0, keepdims=True)
+
+        tot = jnp.max(lax.fori_loop(0, meta[0], flip,
+                                    jnp.zeros((1, B), jnp.int32)))
 
         @pl.when(tot < dlen)
         def _bad():
             err_ref[1:2, :] = jnp.ones((1, B), jnp.int32)
 
-        live = bo > 0
-        full = live & (cov > 0) & (cov == bl)
-        # Flip plane-wide; per-slot live counts drop by each block's
-        # flipped chars (raw counts are unchanged by tombstoning).
-        # Block sums come off ONE plane cumsum via static row reads
-        # (Mosaic has no [NB, K, B] reshape), then gather to logical
-        # slots through ``blkord`` (NB masked adds on the tiny table).
-        ordp[:] = jnp.where(full, -bo, bo)
-        cumfull = _cumsum_rows(jnp.where(full, bl, 0))
-        g = jnp.zeros((NBL, B), jnp.int32)
-        for b_ in range(NB):
-            hi = cumfull[(b_ + 1) * K - 1][jnp.newaxis, :]
-            lo = (cumfull[b_ * K - 1][jnp.newaxis, :] if b_ > 0
-                  else jnp.zeros((1, B), jnp.int32))
-            g = g + jnp.where(blkord[:] == b_, hi - lo, 0)
-        liv[:] = liv[:] - g
-        cumliv[:] = cumliv[:] - _cumsum_rows(g)
+        cumliv[:] = cumliv[:] - _cumsum_rows(liv0 - liv[:])
 
         # The <= 2 live partial runs each contain an endpoint; relocate
         # by order (splits move rows) and 3-way split them.
@@ -904,10 +959,44 @@ def _mixed_rle_kernel(
 
     @pl.when(i == last)
     def _flush():
-        blk_out[:] = blkord[:][jnp.newaxis]
+        @pl.loop(0, NBL)
+        def _(l):
+            blk_out[0, pl.ds(l, 1), :] = jnp.broadcast_to(phys[l], (1, B))
+
         rows_out[:] = rws[:][jnp.newaxis]
-        row0 = lax.broadcasted_iota(jnp.int32, (1, 8, B), 1) == 0
-        meta_out[:] = jnp.where(row0, meta[0], 0)
+        # Row 0: blocks in use; rows 1-2: the integrate counters.
+        row = lax.broadcasted_iota(jnp.int32, (1, 8, B), 1)
+        meta_out[:] = jnp.where(
+            row == 0, meta[0], jnp.where(
+                row == 1, meta[2], jnp.where(row == 2, meta[3], 0)))
+
+
+VMEM_LIMIT_BYTES = 124 * 1024 * 1024  # of the v5e's 128 MiB
+
+
+def _table_rows(ops: OpTensors) -> int:
+    """Rows of each by-order table: everything the compiler knows (remote
+    origins, within-run chains, ranks), packed 128 orders/row, i32 (ROOT
+    -> -1 by u32 wraparound).  One spare tail row for the 2-row run
+    writes, rounded up to a sublane tile."""
+    total_orders = int(np.asarray(ops.order_advance, dtype=np.int64).sum())
+    ocap = max(total_orders + ops.lmax, LANES)
+    rows = (ocap + LANES - 1) // LANES + 1
+    return ((rows + 7) // 8) * 8
+
+
+def vmem_bytes(ops: OpTensors, capacity: int, batch: int = 128,
+               block_k: int = 256, chunk: int = 1024) -> int:
+    """VMEM a pass of the kernel holds for ``ops`` at this geometry,
+    int32 throughout: the five ``[capacity, batch]`` planes, the six
+    by-order tables, the slot tables and block outputs, the
+    double-buffered ``[chunk, batch]`` origin outputs.  Mosaic's own
+    scratch comes on top (about 1 MiB at the merge cell's geometry, whose
+    five planes are 112.8 MiB)."""
+    nblp = max(8, capacity // block_k)
+    words = (5 * capacity * batch + 6 * _table_rows(ops) * LANES
+             + 7 * nblp * batch + 4 * chunk * batch)
+    return 4 * words
 
 
 def make_replayer_rle_mixed(
@@ -926,6 +1015,22 @@ def make_replayer_rle_mixed(
     of any length apply in one step (the one-pass interval delete needs
     no dmax pre-chunking); insert chunks must be <= 128 chars (the
     order-table write window).
+
+    VMEM bounds ``capacity * batch``: five ``[capacity, batch]`` int32
+    planes stay resident for the whole pass, single-buffered (the
+    ``ordp``/``lenp`` output blocks and the ``olp``/``orp``/``rkp``
+    scratch), beside six ``[OT, 128]`` int32 by-order tables (three
+    inputs, ``ordblk`` and two working copies) and the double-buffered
+    ``[chunk, batch]`` origin outputs.  Every pass over the planes
+    (``integrate_fast``, the remote delete, the lookup fallback) walks
+    them a ``block_k``-row block at a time, so no temporary is
+    plane-sized.  Compiled for a described v5e at batch 128, block_k
+    128, chunk 1024: capacity 46,208 (361 blocks, crdt-benchmarks B2.4
+    at N = 6,000) with OT 1,088 needs 120.21 MiB of scoped VMEM, of
+    which the five planes are 112.8 MiB: over a 120 MiB limit, within
+    ``VMEM_LIMIT_BYTES`` (124 MiB; the chip has 128).  ``vmem_bytes``
+    counts a geometry's share, and one over the limit is refused here.
+    Fewer lanes do not help below 128 (the lane axis fills a vreg).
     """
     kinds = np.asarray(ops.kind)
     _require(kinds.ndim == 1, "rle-mixed engine takes one shared stream")
@@ -943,22 +1048,20 @@ def make_replayer_rle_mixed(
         f"insert chunks must be <= {LANES} chars for the order-table "
         f"window (compile with lmax<={LANES})"))
 
-    # By-order tables: everything the compiler knows (remote origins,
-    # within-run chains, ranks), packed 128 orders/row, i32 (ROOT -> -1
-    # by u32 wraparound).  One spare tail row for the 2-row run writes.
-    total_orders = int(np.asarray(ops.order_advance, dtype=np.int64).sum())
-    ocap = max(total_orders + ops.lmax, LANES)
-    OT = (ocap + LANES - 1) // LANES + 1
-    OT = ((OT + 7) // 8) * 8
-    doc0 = prefill_logs(make_flat_doc(8, OT * LANES), ops)
+    OT = _table_rows(ops)
+    need = vmem_bytes(ops, capacity, batch, block_k, chunk)
+    _require(need <= VMEM_LIMIT_BYTES, (
+        f"capacity {capacity} x {batch} lanes needs {need} B of VMEM, "
+        f"over the kernel's {VMEM_LIMIT_BYTES}"))
 
     def table(x):
         return jnp.asarray(
             np.asarray(x, dtype=np.uint32).view(np.int32).reshape(OT, LANES))
 
-    oll0 = table(doc0.ol_log)
-    orl0 = table(doc0.or_log)
-    rkl0 = table(doc0.rank_log)
+    with span("compiler.prefill"):
+        doc0 = prefill_logs(make_flat_doc(8, OT * LANES), ops)
+        tables = (table(doc0.ol_log), table(doc0.or_log),
+                  table(doc0.rank_log))
 
     s = ops.num_steps
     s_pad = max(((s + chunk - 1) // chunk) * chunk, chunk)
@@ -968,20 +1071,28 @@ def make_replayer_rle_mixed(
         return jnp.asarray(np.pad(
             np.asarray(a, dtype=np.uint32).view(np.int32), pad))
 
-    staged = tuple(padded(c) for c in (
-        ops.kind, ops.pos, ops.del_len, ops.del_target, ops.origin_left,
-        ops.origin_right, ops.rank, ops.ins_len, ops.ins_order_start))
+    with span("rle.stage"):
+        staged = tuple(padded(c) for c in (
+            ops.kind, ops.pos, ops.del_len, ops.del_target, ops.origin_left,
+            ops.origin_right, ops.rank, ops.ins_len, ops.ins_order_start))
 
     jitted = _build_mixed_call(s_pad, batch, capacity, block_k, chunk,
                                OT, interpret, fast_integrate)
-    tables = (oll0, orl0, rkl0)
+    built = False
 
     def run() -> RleMixedResult:
-        ol, orr, ordp, lenp, blk, rows, meta, err = jitted(*staged, *tables)
-        return RleMixedResult(
-            ordp=ordp, lenp=lenp, blkord=blk[0], rows=rows[0], meta=meta[0],
-            ol=ol[:s], orr=orr[:s], err=err,
-            block_k=block_k, num_blocks=NB, batch=batch)
+        # As ``ops.rle``'s replayer: the first call builds the program,
+        # every later one enqueues it (wall and thread CPU time).
+        nonlocal built
+        with span("rle.dispatch" if built else "rle.build", cpu=True):
+            ol, orr, ordp, lenp, blk, rows, meta, err = jitted(*staged,
+                                                               *tables)
+            res = RleMixedResult(
+                ordp=ordp, lenp=lenp, blkord=blk[0], rows=rows[0],
+                meta=meta[0], ol=ol[:s], orr=orr[:s], err=err,
+                block_k=block_k, num_blocks=NB, batch=batch)
+        built = True
+        return res
 
     return run
 
@@ -1000,7 +1111,10 @@ def _build_mixed_call(s_pad: int, batch: int, capacity: int,
         (chunk,), lambda i: (i,), memory_space=pltpu.SMEM)
 
     def whole(shape):
+        # One buffer: the block never moves, so a second would only
+        # double the resident planes.
         return pl.BlockSpec(shape, lambda i: tuple(0 for _ in shape),
+                            pipeline_mode=pl.Buffered(1),
                             memory_space=pltpu.VMEM)
 
     call = pl.pallas_call(
@@ -1032,7 +1146,6 @@ def _build_mixed_call(s_pad: int, batch: int, capacity: int,
             jax.ShapeDtypeStruct((8, batch), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((NBLp, batch), jnp.int32),       # blkord
             pltpu.VMEM((NBLp, batch), jnp.int32),       # rws
             pltpu.VMEM((NBLp, batch), jnp.int32),       # liv
             pltpu.VMEM((NBLp, batch), jnp.int32),       # raw
@@ -1044,15 +1157,21 @@ def _build_mixed_call(s_pad: int, batch: int, capacity: int,
             pltpu.VMEM((capacity, batch), jnp.int32),   # olp (run aux)
             pltpu.VMEM((capacity, batch), jnp.int32),   # orp
             pltpu.VMEM((capacity, batch), jnp.int32),   # rkp
-            pltpu.VMEM((capacity, batch), jnp.int32),   # lpp
-            pltpu.SMEM((2,), jnp.int32),                # meta
+            pltpu.SMEM((8,), jnp.int32),                # meta
+            pltpu.SMEM((NBLp,), jnp.int32),             # phys
+            pltpu.SMEM((NBLp,), jnp.int32),             # logi
         ],
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=110 * 1024 * 1024,
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
+        name="rle_mixed_replay",
     )
-    return jax.jit(lambda *a: call(*a))
+
+    def rle_mixed_replay(*args):
+        return call(*args)
+
+    return jax.jit(rle_mixed_replay)
 
 
 def replay_mixed_rle(ops: OpTensors, capacity: int, **kw) -> RleMixedResult:
