@@ -340,7 +340,12 @@ class TestWholePlaneReductions:
             fn = RM._build_mixed_call(128, 8, 256, 8, 128, 8, False, True)
             args = [i32(128)] * 9 + [i32(8, RM.LANES)] * 3
             # Its block maps are SMEM scalars, not slot-table sums, and
-            # its remote delete sums covers block by block.
-            want = {("reduce_sum", (8, 8)): 74, ("reduce_sum", (32, 8)): 46}
+            # its remote delete's plane walk sums covers block by block.
+            # The exact order index took 74 block and 46 slot-table sums
+            # to 66 and 44: the delete's partial-run fix-up (traced twice,
+            # as before: once in the run-by-run delete, once for the
+            # walk's two endpoints) reads its run and slot with one-row
+            # loads, and so does ``split``'s index upkeep.
+            want = {("reduce_sum", (8, 8)): 66, ("reduce_sum", (32, 8)): 44}
         c = _kernel_reductions(fn, args)
         assert {k: c[k] for k in want} == want

@@ -1,11 +1,12 @@
 """Mixed-stream RLE run engine (remote ops on run rows) vs oracle.
 
 Interpreter-mode differential tests. Tiny blocks (block_k=8) force leaf
-SPLITS between remote lookups, exercising the stale-ordblk fallback and
-self-heal on the run representation; the scenarios mirror
+SPLITS between remote lookups, exercising the order index's upkeep
+through splits on the run representation; the scenarios mirror
 ``test_blocked_mixed`` (the `doc.rs:242-348` apply paths) plus the
 config-4 concurrent-insert storm and cross-engine local equality with
-``ops.rle``, and the kernel's integrate counters.
+``ops.rle``, the kernel's integrate and lookup counters, and both paths
+of the remote delete.
 """
 import random
 
@@ -145,7 +146,7 @@ class TestMixedRleRemote:
     def test_storm_interleaved_peers(self):
         # N peers typing concurrently at interleaved positions, merged into
         # one causal stream — splits hit between remote integrations,
-        # exercising the stale-index fallback + heal on run rows.
+        # exercising the order index's upkeep on run rows.
         rng = random.Random(99)
         peers = []
         for name in ("ada", "bea", "cyd", "dot"):
@@ -330,3 +331,128 @@ class TestIntegrateCounters:
             RM.make_replayer_rle_mixed(ops, capacity=2 * 46208, batch=128,
                                        block_k=128, chunk=1024,
                                        interpret=True)
+
+
+def _word(rng, lo, hi):
+    n = rng.randint(lo, hi)
+    return "".join(rng.choice("abcdefghij") for _ in range(n))
+
+
+def b24_history(agent, rounds, rng, doc=None):
+    """A history shaped like crdt-benchmarks B2.4 ("concurrently insert &
+    delete"), made by the oracle: each round a word of 3-9 letters at a
+    uniform position, then with p = 0.5 a second word of 2-10 letters at
+    the same index, else a delete of 1 to min(9, rest) chars there."""
+    doc = ListCRDT() if doc is None else doc
+    a = doc.get_or_create_agent_id(agent)
+    for _ in range(rounds):
+        pos = rng.randint(0, len(doc))
+        doc.local_insert(a, pos, _word(rng, 3, 9))
+        if rng.random() < 0.5:
+            doc.local_insert(a, pos, _word(rng, 2, 10))
+        else:
+            doc.local_delete(a, pos, rng.randint(1, min(9, len(doc) - pos)))
+    return doc
+
+
+class TestExactOrderIndex:
+    """``ordblk`` stays exact through block splits, the only move of run
+    rows across blocks: on streams whose splits move runs (block_k 8),
+    every order lookup hits its indexed block, so ``lookup_counts`` reads
+    lookups and no walk, and the document equals the oracle's,
+    tombstones included."""
+
+    @staticmethod
+    def _replay(txns, capacity):
+        table = B.AgentTable()
+        for t in txns:
+            table.add(t.id.agent)
+        ops, _ = B.compile_remote_txns(txns, table, lmax=16, dmax=None)
+        res = RM.replay_mixed_rle(ops, capacity=capacity, batch=8,
+                                  block_k=8, chunk=128, interpret=True)
+        res.check()
+        for lane in range(res.batch):
+            lookups, walks = res.lookup_counts(lane)
+            assert lookups > 0 and walks == 0, (lane, lookups, walks)
+        oracle = oracle_txns(txns)
+        doc = R.rle_to_flat(ops, res)
+        assert SA.to_string(doc) == oracle.to_string()
+        assert SA.doc_spans(doc) == oracle.doc_spans()
+        return res
+
+    @staticmethod
+    def _blocks_of(res, lo, hi):
+        """Physical blocks of lane 0 holding a run that meets orders
+        ``[lo, hi)``."""
+        o = np.asarray(res.ordp)[:, 0]
+        n = np.asarray(res.lenp)[:, 0]
+        s = np.abs(o) - 1
+        hit = (o != 0) & (s < hi) & (s + n > lo)
+        return set((np.flatnonzero(hit) // res.block_k).tolist())
+
+    def test_b24_two_clients_merge_without_a_walk(self):
+        rng = random.Random(2024)
+        txns = []
+        for agent in ("A", "B"):
+            txns += export_txns_since(b24_history(agent, 40, rng), 0)
+        res = self._replay(txns, capacity=1024)
+        assert int(np.asarray(res.meta)[0, 0]) >= 24  # dozens of splits
+
+    def test_long_run_moved_by_a_split_then_deleted(self):
+        # amy types 300 chars in order: ONE run (the chain merge), wider
+        # than the 2-row window of a run write.  Single chars typed at
+        # the head push it down its block until a split moves it to a new
+        # block; bob then deletes a range past its 256th order.
+        doc = ListCRDT()
+        a = doc.get_or_create_agent_id("amy")
+        for k in range(30):
+            doc.local_insert(a, 10 * k, "x" * 10)
+        for _ in range(8):
+            doc.local_insert(a, 0, "h")
+        txns = export_txns_since(doc, 0)
+        txns.append(RemoteTxn(
+            id=RemoteId("bob", 0), parents=[RemoteId("amy", 307)],
+            ops=[RemoteDel(RemoteId("amy", 270), 12)]))
+        res = self._replay(txns, capacity=64)
+        assert 0 not in self._blocks_of(res, 0, 300)
+
+    @pytest.fixture
+    def delete_run_cost(self, request, monkeypatch):
+        """The kernel reads ``DELETE_RUN_COST`` when it is traced: patch
+        it, and drop the cached builds on both sides of the test."""
+        monkeypatch.setattr(RM, "DELETE_RUN_COST", request.param)
+        RM._build_mixed_call.cache_clear()
+        yield
+        RM._build_mixed_call.cache_clear()
+
+    # DELETE_RUN_COST 0 sends every remote delete run by run; 2**20 sends
+    # every one through the walk over the blocks in use.
+    @pytest.mark.parametrize("delete_run_cost", [0, 1 << 20],
+                             ids=["runs", "walk"], indirect=True)
+    def test_remote_delete_paths(self, delete_run_cost):
+        rng = random.Random(11)
+        doc = b24_history("amy", 20, rng)
+        a = doc.get_or_create_agent_id("amy")
+        s0 = doc.get_next_order()
+        for _ in range(6):  # order-contiguous words at scattered places
+            doc.local_insert(a, rng.randint(0, len(doc)), _word(rng, 4, 6))
+        s1 = doc.get_next_order()
+        b24_history("amy", 20, rng, doc)  # later rounds kill some of them
+        txns = export_txns_since(doc, 0)
+        last = [RemoteId("amy", doc.get_next_order() - 1)]
+        # Two concurrent, overlapping deletes, each starting and ending
+        # inside a word: the second is idempotent where they overlap.
+        t, n = s0 + 2, s1 - s0 - 4
+        kills = [
+            RemoteTxn(id=RemoteId("zed", 0), parents=last,
+                      ops=[RemoteDel(RemoteId("amy", t), n)]),
+            RemoteTxn(id=RemoteId("yan", 0), parents=last,
+                      ops=[RemoteDel(RemoteId("amy", t - 1), n - 2)]),
+        ]
+        before = oracle_txns(txns)
+        orders = before.order[:before.n]
+        in_range = (orders >= t) & (orders < t + n)
+        dead = before.deleted[:before.n][in_range]
+        assert dead.any() and not dead.all()  # live and dead runs
+        res = self._replay(txns + kills, capacity=512)
+        assert len(self._blocks_of(res, t, t + n)) >= 3

@@ -16,11 +16,12 @@ What the remote paths add on top of ``ops.rle``'s block grid:
   the block descent needs the `FullIndex` pair (`index.rs:100-158`) —
   live sums for local edits, raw sums for integrate cursors.
 - **order -> physical-block index** (``ordblk``, the `markers.rs:8` /
-  `split_list/mod.rs:440` SpaceIndex analog) packed 128 orders/row.
-  Maintained per insert; a block split moves rows and deliberately
-  leaves entries stale — lookups verify containment against the hinted
-  block (runs make that a range test, not an equality test) and fall
-  back to ONE walk over the blocks in use, then self-heal.
+  `split_list/mod.rs:440` SpaceIndex analog) packed 128 orders/row,
+  kept EXACT: written per insert, and a block split (the only move of
+  run rows across blocks) rewrites every order of the runs it moves.
+  Lookups verify containment in the indexed block (runs make that a
+  range test, not an equality test); a walk over the blocks in use
+  stays only as a guard, counted in ``meta`` (``lookup_counts``).
 - **by-order origin/rank tables** (``oll/orl/rkl``) prefilled host-side
   (`batch.prefill_logs`), updated in-kernel by local inserts — the YATA
   scan reads per-ORDER origins, which the prefilled implicit chain
@@ -31,11 +32,12 @@ What the remote paths add on top of ``ops.rle``'s block grid:
   only break mid-run at the op's ``origin_right`` — each loop iteration
   therefore consumes a WHOLE run (or jumps straight to origin_right
   inside it), shrinking the scan by the run factor.
-- **one-pass remote delete**: runs are disjoint ORDER intervals, so a
+- **remote delete by order**: runs are disjoint ORDER intervals, so a
   target range ``[t, t+dlen)`` fully covers every run it touches except
-  at most the two holding its endpoints — one walk over the blocks in
-  use flips the full covers (each block's flipped chars off its slot's
-  live count) plus <= 2 by-order endpoint fix-ups (3-way splits).
+  at most the two holding its endpoints.  A short range is taken run
+  by run through the exact index (flip a full cover in place, 3-way
+  split a partial one); a long one by one walk over the blocks in use
+  that flips the full covers, plus <= 2 by-order endpoint fix-ups.
   Already-dead covered runs count toward the idempotency total without
   flipping (idempotent concurrent deletes, `double_delete.rs:6-9`;
   excess counting stays host-side per SURVEY).  Any ``dlen`` in one
@@ -78,6 +80,19 @@ from .rle import (
 from .span_arrays import make_flat_doc
 
 LANES = 128  # orders per by-order table row
+
+# A remote delete of ``dlen`` orders goes run by run (at most ``dlen``
+# iterations) when ``dlen * DELETE_RUN_COST`` is at most the blocks in
+# use, else by one walk over them.  The constant is one run-loop
+# iteration's device time over one plane-walk block's, measured on a
+# v5e over the crdt-benchmarks B2.4 merge stream (N = 6,000, 128 lanes,
+# block_k 128; PERF.md §6): walking the blocks twice instead of once
+# added 158.9 ms over 1,588,946 blocks (0.100 us a block); a second run
+# loop over each range, its runs already tombstoned, added 5.76 ms over
+# 8,937 iterations (0.645 us, a ratio of 6.4); an iteration that
+# tombstones also stores its row and updates the slot counts, which
+# rounds the ratio up to 8.
+DELETE_RUN_COST = 8
 
 
 def _locate_run_raw(bo, bl, idx_k, r0, local):
@@ -132,8 +147,9 @@ def _insert_splice_raw(bo, bl, idx_k, c, i_r, o_r, l_r, off, il, st,
 
 
 class RleMixedResult(RleResult):
-    """``RleResult`` + the order-index error flag (err row 2) and the
-    integrate counters (``meta`` rows 1 and 2, see ``integrate_counts``)."""
+    """``RleResult`` + the order-index error flag (err row 2), the
+    integrate counters (``meta`` rows 1 and 2, see ``integrate_counts``)
+    and the order-lookup counters (rows 3 and 4, ``lookup_counts``)."""
 
     def integrate_counts(self, lane: int = 0):
         """(remote inserts, those whose fast scan flagged and ran the exact
@@ -141,6 +157,13 @@ class RleMixedResult(RleResult):
         walk when the replayer was built with ``fast_integrate=False``."""
         meta = np.asarray(self.meta)
         return int(meta[1, lane]), int(meta[2, lane])
+
+    def lookup_counts(self, lane: int = 0):
+        """(order -> run lookups, those whose indexed block missed and ran
+        the walk over every block in use) of one lane's pass.  The index
+        is exact, so a walk means an order absent from the state."""
+        meta = np.asarray(self.meta)
+        return int(meta[3, lane]), int(meta[4, lane])
 
     def check(self) -> None:
         super().check()
@@ -221,6 +244,8 @@ def _mixed_rle_kernel(
         meta[0] = 1  # logical blocks in use
         meta[2] = 0  # remote inserts
         meta[3] = 0  # ... of which ran the exact serial walk
+        meta[5] = 0  # order lookups
+        meta[6] = 0  # ... of which walked every block in use
 
     # ---- by-order tables (order o lives at [o // 128, o % 128]) ---------
 
@@ -241,7 +266,30 @@ def _mixed_rle_kernel(
         hit = (g >= start) & (g < start + run_len)
         tab[pl.ds(r0, 2), :] = jnp.where(hit, v, w)
 
+    def tab_fill(tab, start, run_len, v):
+        """tab[start : start+run_len] = v for a run of any length: the
+        local merge path grows runs past 128 orders, and those take one
+        masked write per table row they span."""
+
+        @pl.when(run_len <= LANES)
+        def _():
+            tab_write_run(tab, start, run_len, v)
+
+        @pl.when(run_len > LANES)
+        def _():
+            @pl.loop(start // LANES, (start + run_len - 1) // LANES + 1)
+            def _(r):
+                g = r * LANES + lane
+                w = tab[pl.ds(r, 1), :]
+                tab[pl.ds(r, 1), :] = jnp.where(
+                    (g >= start) & (g < start + run_len), v, w)
+
     # ---- slot plumbing (logical block tables) ---------------------------
+
+    def row_at(ref, r):
+        """Row ``r`` of a lane-replicated VMEM plane or table, as one
+        scalar: a one-row load, not a masked reduction."""
+        return _lane_scalar(ref[pl.ds(r, 1), :])
 
     def slot_scalar(tbl, l):
         return _lane_scalar(jnp.where(idx_l == l, tbl[:], 0))
@@ -281,7 +329,8 @@ def _mixed_rle_kernel(
         ``l``'s rows to a fresh physical block spliced into the logical
         order at ``l+1``.  At table capacity the split is a NO-OP with the
         error flag raised (advisor r3: proceeding overwrote a live block).
-        ``ordblk`` entries of moved rows go stale; lookups self-heal."""
+        The only move of run rows across blocks, so it rewrites the
+        ``ordblk`` entries of every order of the runs it moves."""
         nlog = meta[0]
 
         @pl.when(nlog >= NB)
@@ -318,6 +367,13 @@ def _mixed_rle_kernel(
                 ap[pl.ds(nb * K, K), :] = jnp.where(
                     new_mask, _shift_rows_up(ax, keep, K), 0)
                 ap[pl.ds(b * K, K), :] = jnp.where(keep_mask, ax, 0)
+
+            # The moved runs' orders, tombstones too, now live in nb.
+            @pl.loop(0, mv)
+            def _(j):
+                tab_fill(ordblk, jnp.abs(row_at(ordp, nb * K + j)) - 1,
+                         row_at(lenp, nb * K + j), nb)
+
             # Logical positions: blocks after slot l shift one slot
             # down; the moved-out top half (new physical block nb)
             # lands at slot l + 1.
@@ -366,22 +422,26 @@ def _mixed_rle_kernel(
 
     def locate_order(o):
         """(physical block, row) of the run containing order ``o``.
-        ``ordblk`` is a HINT — splits leave it stale; verify, fall back to
-        one walk over the blocks in use, self-heal the entry.
+        ``ordblk`` is exact: verify the indexed block; the walk over the
+        blocks in use is a counted guard that only an order absent from
+        the state reaches.
 
         Callers may pass the ROOT sentinel (-1) from a discarded
         ``jnp.where`` branch (both branches evaluate): the lookup then
-        returns in-range garbage without raising the miss flag or
-        touching the hint table."""
+        returns in-range garbage without counting, walking, raising the
+        miss flag or touching the index."""
         oc = jnp.maximum(o, 0)
         bh = jnp.clip(tab_read(ordblk, oc), 0, NB - 1)
         f, row = find_in_block(bh, oc)
         # As ``integrate_entry``: the walk is predicated and its answer
         # passed through SMEM, not returned from a lax.cond branch.
-        meta[4] = bh * K + row
+        meta[4] = bh * K + jnp.minimum(row, K - 1)
+        meta[5] = meta[5] + jnp.where(o >= 0, 1, 0)
 
-        @pl.when(~f)
+        @pl.when(~f & (o >= 0))
         def _fallback():
+            meta[6] = meta[6] + 1
+
             def walk(b, g):
                 bo = blk(ordp, b)
                 so = jnp.abs(bo) - 1
@@ -394,19 +454,13 @@ def _mixed_rle_kernel(
                                       jnp.full((1, B), CAP, jnp.int32)))
             ok = g < CAP
 
-            @pl.when(~ok & (o >= 0))
+            @pl.when(~ok)
             def _missing():
                 err_ref[2:3, :] = jnp.ones((1, B), jnp.int32)
 
             meta[4] = jnp.where(ok, g, CAP - 1)
 
-        b, row = meta[4] // K, meta[4] % K
-
-        @pl.when(o >= 0)
-        def _heal():
-            tab_write(ordblk, oc, b)
-
-        return b, row
+        return meta[4] // K, meta[4] % K
 
     def pos_of_order(o):
         """RAW document position of the char with order ``o``."""
@@ -812,89 +866,116 @@ def _mixed_rle_kernel(
 
     # ---- remote delete (`doc.rs:295-340`) -------------------------------
 
-    def retire_endpoint(t, dlen, o):
-        """Split the covered sub-range out of the run containing order
-        ``o`` (one former-walk iteration).  No-op unless that run is
-        LIVE and PARTIALLY covered — full covers were flipped by the
-        caller's plane pass, dead runs are idempotent retires."""
-        b, row = locate_order(o)
-        l = logi[b]
+    def run_cover(b, row, t, dlen):
+        """Signed start order, length and 0-based start of the run at
+        (block ``b``, ``row``), and the part ``[a, e)`` of it that
+        ``[t, t+dlen)`` covers (run-relative; empty when ``e <= a``)."""
+        o_r = row_at(ordp, b * K + row)
+        l_r = row_at(lenp, b * K + row)
+        so = jnp.abs(o_r) - 1
+        return (o_r, l_r, so, jnp.maximum(t - so, 0),
+                jnp.minimum(l_r, t + dlen - so))
 
-        def run_facts():
-            bo = ordp[pl.ds(b * K, K), :]
-            bl = lenp[pl.ds(b * K, K), :]
-            o_r = _row_scalar(bo, row, idx_k)
-            l_r = _row_scalar(bl, row, idx_k)
-            so = jnp.abs(o_r) - 1
-            a = jnp.maximum(t - so, 0)
-            e = jnp.minimum(l_r, t + dlen - so)
-            return bo, bl, o_r, l_r, so, a, e
+    def retire_partial(t, dlen, o, l):
+        """Split the covered sub-range out of the LIVE, PARTLY covered
+        run that holds order ``o`` in slot ``l``: [head?] [tombstone
+        mid] [tail?], splitting the slot first when it is full."""
 
-        _, _, o_r, l_r, so, a, e = run_facts()
-        partial = (o_r > 0) & ((a > 0) | (e < l_r)) & (e > a)
-
-        @pl.when(partial & (slot_scalar(rws, l) + 2 > K))
+        @pl.when(row_at(rws, l) + 2 > K)
         def _():
             split(l)
 
-        @pl.when(partial)
-        def _fix():
-            b2, row2 = locate_order(o)  # split may have moved the run
-            l2 = logi[b2]
-            bo = ordp[pl.ds(b2 * K, K), :]
-            bl = lenp[pl.ds(b2 * K, K), :]
-            o_r = _row_scalar(bo, row2, idx_k)
-            l_r = _row_scalar(bl, row2, idx_k)
-            so = jnp.abs(o_r) - 1
-            a = jnp.maximum(t - so, 0)
-            e = jnp.minimum(l_r, t + dlen - so)
-            cov = e - a
-            has_head = a > 0
-            has_tail = e < l_r
-            amt = has_head.astype(jnp.int32) + has_tail.astype(jnp.int32)
-            sh_o = _shift_rows(bo, amt, 2)
-            sh_l = _shift_rows(bl, amt, 2)
-            no = jnp.where(idx_k <= row2, bo, sh_o)
-            nl = jnp.where(idx_k <= row2, bl, sh_l)
-            # Part layout: [head?] [tombstone mid] [tail?].
-            p0o = jnp.where(has_head, o_r, -(so + a + 1))
-            p0l = jnp.where(has_head, a, cov)
-            p1o = jnp.where(has_head, -(so + a + 1), so + e + 1)
-            p1l = jnp.where(has_head, cov, l_r - e)
-            w0 = idx_k == row2
-            no = jnp.where(w0, p0o, no)
-            nl = jnp.where(w0, p0l, nl)
-            w1 = (idx_k == row2 + 1) & (amt >= 1)
-            no = jnp.where(w1, p1o, no)
-            nl = jnp.where(w1, p1l, nl)
-            w2 = (idx_k == row2 + 2) & (amt == 2)
-            no = jnp.where(w2, so + e + 1, no)
-            nl = jnp.where(w2, l_r - e, nl)
-            ordp[pl.ds(b2 * K, K), :] = no
-            lenp[pl.ds(b2 * K, K), :] = nl
-            # Aux pieces: piece 0 keeps the original head; later pieces
-            # chain to their predecessor char (shared 3-way-split
-            # transform, see rle._split_piece_aux).
-            aux_out = _split_piece_aux(
-                (olp[pl.ds(b2 * K, K), :], orp[pl.ds(b2 * K, K), :],
-                 rkp[pl.ds(b2 * K, K), :]),
-                idx_k, row2, amt, w1, w2, so, a, e, has_head)
-            for ap, na in zip((olp, orp, rkp), aux_out):
-                ap[pl.ds(b2 * K, K), :] = na
-            rws[pl.ds(l2, 1), :] = rws[pl.ds(l2, 1), :] + amt
-            liv[pl.ds(l2, 1), :] = liv[pl.ds(l2, 1), :] - cov
-            cumliv[:] = jnp.where(idx_l >= l2, cumliv[:] - cov,
-                                  cumliv[:])
+        b2, row2 = locate_order(o)  # split may have moved the run
+        l2 = logi[b2]
+        bo = ordp[pl.ds(b2 * K, K), :]
+        bl = lenp[pl.ds(b2 * K, K), :]
+        o_r, l_r, so, a, e = run_cover(b2, row2, t, dlen)
+        cov = e - a
+        has_head = a > 0
+        has_tail = e < l_r
+        amt = has_head.astype(jnp.int32) + has_tail.astype(jnp.int32)
+        sh_o = _shift_rows(bo, amt, 2)
+        sh_l = _shift_rows(bl, amt, 2)
+        no = jnp.where(idx_k <= row2, bo, sh_o)
+        nl = jnp.where(idx_k <= row2, bl, sh_l)
+        p0o = jnp.where(has_head, o_r, -(so + a + 1))
+        p0l = jnp.where(has_head, a, cov)
+        p1o = jnp.where(has_head, -(so + a + 1), so + e + 1)
+        p1l = jnp.where(has_head, cov, l_r - e)
+        w0 = idx_k == row2
+        no = jnp.where(w0, p0o, no)
+        nl = jnp.where(w0, p0l, nl)
+        w1 = (idx_k == row2 + 1) & (amt >= 1)
+        no = jnp.where(w1, p1o, no)
+        nl = jnp.where(w1, p1l, nl)
+        w2 = (idx_k == row2 + 2) & (amt == 2)
+        no = jnp.where(w2, so + e + 1, no)
+        nl = jnp.where(w2, l_r - e, nl)
+        ordp[pl.ds(b2 * K, K), :] = no
+        lenp[pl.ds(b2 * K, K), :] = nl
+        # Aux pieces: piece 0 keeps the original head; later pieces
+        # chain to their predecessor char (shared 3-way-split
+        # transform, see rle._split_piece_aux).
+        aux_out = _split_piece_aux(
+            (olp[pl.ds(b2 * K, K), :], orp[pl.ds(b2 * K, K), :],
+             rkp[pl.ds(b2 * K, K), :]),
+            idx_k, row2, amt, w1, w2, so, a, e, has_head)
+        for ap, na in zip((olp, orp, rkp), aux_out):
+            ap[pl.ds(b2 * K, K), :] = na
+        rws[pl.ds(l2, 1), :] = rws[pl.ds(l2, 1), :] + amt
+        liv[pl.ds(l2, 1), :] = liv[pl.ds(l2, 1), :] - cov
+        cumliv[:] = jnp.where(idx_l >= l2, cumliv[:] - cov, cumliv[:])
 
-    def do_remote_delete(t, dlen):
-        """One-pass ORDER-interval tombstone (`doc.rs:295-340` without
-        the fragmentation walk; see ops.rle_lanes_mixed): runs are
-        disjoint order intervals, so [t, t+dlen) fully covers every run
-        it touches except at most the two holding its endpoints — flip
-        the full covers plane-wide, fix up the <= 2 partial runs by
-        order lookup, and count covered DEAD runs toward the
-        idempotency total (`double_delete.rs:6-9`).  Any ``dlen`` in
-        one step — no dmax pre-chunking."""
+    def retire_endpoint(t, dlen, o):
+        """``retire_partial`` on the run containing order ``o`` if it is
+        LIVE and PARTIALLY covered — full covers were flipped by the
+        plane walk, dead runs are idempotent retires."""
+        b, row = locate_order(o)
+        o_r, l_r, _, a, e = run_cover(b, row, t, dlen)
+
+        @pl.when((o_r > 0) & ((a > 0) | (e < l_r)) & (e > a))
+        def _():
+            retire_partial(t, dlen, o, logi[b])
+
+    def delete_by_runs(t, dlen):
+        """Tombstone ``[t, t+dlen)`` run by run through the exact index:
+        one iteration per run the range touches, each locating the run
+        that holds the next order, counting its covered chars (dead runs
+        too), flipping it in place if it is live and wholly covered, or
+        3-way splitting it if live and partly covered.  Returns the
+        covered total."""
+
+        def body(carry):
+            o, tot = carry
+            b, row = locate_order(o)
+            l = logi[b]
+            o_r, l_r, so, a, e = run_cover(b, row, t, dlen)
+            full = (o_r > 0) & (a == 0) & (e == l_r)
+
+            @pl.when(full)
+            def _flip():
+                g = b * K + row
+                ordp[pl.ds(g, 1), :] = -ordp[pl.ds(g, 1), :]
+                liv[pl.ds(l, 1), :] = liv[pl.ds(l, 1), :] - l_r
+                cumliv[:] = jnp.where(idx_l >= l, cumliv[:] - l_r,
+                                      cumliv[:])
+
+            @pl.when((o_r > 0) & ~full & (e > a))
+            def _part():
+                retire_partial(t, dlen, o, l)
+
+            # At least one order on: a missed lookup (err row 2) cannot
+            # stall the loop.
+            return jnp.maximum(so + l_r, o + 1), tot + (e - a)
+
+        _, tot = lax.while_loop(lambda c: c[0] < t + dlen, body,
+                                (t, jnp.int32(0)))
+        return tot
+
+    def delete_by_walk(t, dlen):
+        """Tombstone ``[t, t+dlen)`` in one walk over the blocks in use,
+        flipping the full covers, then the <= 2 live partial runs (each
+        holds an endpoint) by order.  Returns the covered total."""
         liv0 = liv[:]
 
         def flip(b, tot):
@@ -916,17 +997,39 @@ def _mixed_rle_kernel(
 
         tot = jnp.max(lax.fori_loop(0, meta[0], flip,
                                     jnp.zeros((1, B), jnp.int32)))
-
-        @pl.when(tot < dlen)
-        def _bad():
-            err_ref[1:2, :] = jnp.ones((1, B), jnp.int32)
-
         cumliv[:] = cumliv[:] - _cumsum_rows(liv0 - liv[:])
 
-        # The <= 2 live partial runs each contain an endpoint; relocate
-        # by order (splits move rows) and 3-way split them.
-        retire_endpoint(t, dlen, t + dlen - 1)
-        retire_endpoint(t, dlen, t)
+        # The last endpoint, then the first: one traced fix-up body.
+        @pl.loop(0, 2)
+        def _(j):
+            retire_endpoint(t, dlen, jnp.where(j == 0, t + dlen - 1, t))
+
+        return tot
+
+    def do_remote_delete(t, dlen):
+        """ORDER-interval tombstone (`doc.rs:295-340` without the
+        fragmentation walk; see ops.rle_lanes_mixed): runs are disjoint
+        order intervals, so [t, t+dlen) fully covers every run it
+        touches except at most the two holding its endpoints.  Covered
+        DEAD runs count toward the idempotency total
+        (`double_delete.rs:6-9`).  Any ``dlen`` in one step — no dmax
+        pre-chunking.  A range touches at most ``dlen`` runs, so it goes
+        run by run unless that could cost more than a walk over the
+        blocks in use (``DELETE_RUN_COST``)."""
+        by_runs = dlen * DELETE_RUN_COST <= meta[0]
+
+        def covered(tot):
+            @pl.when(tot < dlen)
+            def _bad():
+                err_ref[1:2, :] = jnp.ones((1, B), jnp.int32)
+
+        @pl.when(by_runs)
+        def _():
+            covered(delete_by_runs(t, dlen))
+
+        @pl.when(~by_runs)
+        def _():
+            covered(delete_by_walk(t, dlen))
 
     # ---- dispatch -------------------------------------------------------
 
@@ -964,11 +1067,13 @@ def _mixed_rle_kernel(
             blk_out[0, pl.ds(l, 1), :] = jnp.broadcast_to(phys[l], (1, B))
 
         rows_out[:] = rws[:][jnp.newaxis]
-        # Row 0: blocks in use; rows 1-2: the integrate counters.
+        # Row 0: blocks in use; rows 1-2: the integrate counters; rows
+        # 3-4: the order-lookup counters.
         row = lax.broadcasted_iota(jnp.int32, (1, 8, B), 1)
-        meta_out[:] = jnp.where(
-            row == 0, meta[0], jnp.where(
-                row == 1, meta[2], jnp.where(row == 2, meta[3], 0)))
+        out = jnp.zeros((1, 8, B), jnp.int32)
+        for r, m in enumerate((0, 2, 3, 5, 6)):
+            out = jnp.where(row == r, meta[m], out)
+        meta_out[:] = out
 
 
 VMEM_LIMIT_BYTES = 124 * 1024 * 1024  # of the v5e's 128 MiB
@@ -1012,7 +1117,7 @@ def make_replayer_rle_mixed(
     build a jitted replayer.
 
     ``capacity`` counts RUN rows (`ops.rle` contract).  Remote deletes
-    of any length apply in one step (the one-pass interval delete needs
+    of any length apply in one step (the order-interval delete needs
     no dmax pre-chunking); insert chunks must be <= 128 chars (the
     order-table write window).
 
@@ -1022,7 +1127,7 @@ def make_replayer_rle_mixed(
     scratch), beside six ``[OT, 128]`` int32 by-order tables (three
     inputs, ``ordblk`` and two working copies) and the double-buffered
     ``[chunk, batch]`` origin outputs.  Every pass over the planes
-    (``integrate_fast``, the remote delete, the lookup fallback) walks
+    (``integrate_fast``, a long remote delete, the lookup guard) walks
     them a ``block_k``-row block at a time, so no temporary is
     plane-sized.  Compiled for a described v5e at batch 128, block_k
     128, chunk 1024: capacity 46,208 (361 blocks, crdt-benchmarks B2.4
