@@ -12,7 +12,7 @@ whole BASELINE.json table and writes it to ``BENCH_ALL.json``:
 3. ragged mixed corpus (rustcode + sveltecomponent) — divergent doc
    GROUPS on the rle engine's grid dimension.
 4. N-peer concurrent-insert storm (tiebreak-heavy) — remote ops on the
-   mixed blocked engine.
+   mixed RLE run engine (``ops.rle_mixed``).
 5. streaming apply, delete-heavy, per-doc DIVERGENT streams on the
    per-lane rle engine, warm-started across chunks with checkpoint
    resync.
@@ -528,10 +528,9 @@ def cfg_northstar(args):
     stays equal-workload: the native C++ engine replays the ORIGINAL
     per-patch stream, and ``ops`` counts original patches.
     """
-    from text_crdt_rust_tpu.config import engines_for
-    from text_crdt_rust_tpu.ops import blocked as BL
-    from text_crdt_rust_tpu.ops import blocked_hbm as BH
+    from text_crdt_rust_tpu.config import engines_for, supports_fused_steps
     from text_crdt_rust_tpu.ops import rle as R
+    from text_crdt_rust_tpu.ops import rle_hbm as RH
 
     if args.engine not in engines_for("northstar"):
         raise ValueError(
@@ -542,7 +541,6 @@ def cfg_northstar(args):
     if args.patches:
         patches = patches[:args.patches]
     n_ops = len(patches)
-    ins_total = sum(len(p.ins_content) for p in patches)
     # Default geometry (rle): 512 lanes at the measured-optimum capacity
     # 20,992 (r5 sweep). A user-supplied LARGER --capacity falls back to
     # 256 lanes: 512-lane planes exceed the VMEM budget at 32k+ rows
@@ -559,81 +557,56 @@ def cfg_northstar(args):
     assert base_str == want
 
     fstats = None
-    if args.engine in ("rle", "rle-hbm"):
-        from text_crdt_rust_tpu.ops import rle_hbm as RH
-
-        merged = B.merge_patches(patches)
-        lmax = max([len(p.ins_content) for p in merged] + [1])
-        ops, _ = B.compile_local_patches(merged, lmax=lmax, dmax=None)
-        # Generalized step fusion (ISSUE 6): fold the shapes the host
-        # coalescer cannot reach — replace pairs (delete+insert at one
-        # position land as ONE dual-branch step) and backwards insert
-        # bursts (W-row fused splices) — on the fused-splice engines.
-        # --fuse-w 1 disables; default 8 honors every K's headroom.
-        from text_crdt_rust_tpu.config import supports_fused_steps
-        fuse_w = args.fuse_w or 8
-        if fuse_w > 1 and supports_fused_steps(args.engine):
-            ops, fstats = B.fuse_steps(ops, fuse_w=fuse_w)
-        # K=128 x 512 lanes x capacity 20,992 is the measured optimum
-        # (r5 sweep, committed as perf/sweep_r4.json — written by
-        # perf/sweep_r4.py: 3.80G ops/s vs 2.63G at the old 256x32768);
-        # the HBM variant holds 1024+ lanes (verdict item 2's batch bar)
-        # and G doc GROUPS multiply the concurrent-document count to the
-        # 10k of the north-star statement in ONE kernel launch.
-        groups = max(args.groups, 1)
-        stream = [ops] * groups if groups > 1 else ops
-        if args.engine == "rle-hbm":
-            block_k = 512
-            capacity = args.capacity or 32768
-            capacity = ((capacity + block_k - 1) // block_k) * block_k
-            maker = partial(RH.make_replayer_rle_hbm, block_k=block_k)
-        else:
-            block_k = 128
-            capacity = args.capacity or 20992  # RUN rows, not chars
-            capacity = ((capacity + block_k - 1) // block_k) * block_k
-            maker = partial(R.make_replayer_rle, block_k=block_k)
-        log(f"[northstar] {args.trace}[:{n_ops}] -> {ops.num_steps} merged "
-            f"steps, capacity {capacity} runs, batch {batch} x {groups} "
-            f"group(s), engine {args.engine}")
-        run = maker(stream, capacity=capacity, batch=batch,
-                    chunk=args.chunk, interpret=args.interpret)
-        hbm = groups * (2 * capacity * batch * 4
-                        + 2 * ops.num_steps * batch * 4)
-        if groups > 1:
-            def to_flat(ops_, res_list):
-                # Verify EVERY group's doc 0 (identical streams).
-                docs = [R.rle_to_flat(ops_, r) for r in res_list]
-                for d in docs[1:]:
-                    assert SA.to_string(d) == SA.to_string(docs[0])
-                return docs[0]
-        else:
-            to_flat = R.rle_to_flat
+    merged = B.merge_patches(patches)
+    lmax = max([len(p.ins_content) for p in merged] + [1])
+    ops, _ = B.compile_local_patches(merged, lmax=lmax, dmax=None)
+    # Generalized step fusion: fold the shapes the host
+    # coalescer cannot reach — replace pairs (delete+insert at one
+    # position land as ONE dual-branch step) and backwards insert
+    # bursts (W-row fused splices) — on the fused-splice engines.
+    # --fuse-w 1 disables; default 8 honors every K's headroom.
+    fuse_w = args.fuse_w or 8
+    if fuse_w > 1 and supports_fused_steps(args.engine):
+        ops, fstats = B.fuse_steps(ops, fuse_w=fuse_w)
+    # K=128 x 512 lanes x capacity 20,992 is the measured optimum
+    # (r5 sweep, committed as perf/sweep_r4.json — written by
+    # perf/sweep_r4.py: 3.80G ops/s vs 2.63G at the old 256x32768);
+    # the HBM variant holds 1024+ lanes (verdict item 2's batch bar)
+    # and G doc GROUPS multiply the concurrent-document count to the
+    # 10k of the north-star statement in ONE kernel launch.
+    groups = max(args.groups, 1)
+    stream = [ops] * groups if groups > 1 else ops
+    if args.engine == "rle-hbm":
+        block_k = 512
+        capacity = args.capacity or 32768
+        capacity = ((capacity + block_k - 1) // block_k) * block_k
+        maker = partial(RH.make_replayer_rle_hbm, block_k=block_k)
     else:
-        capacity = 2 << int(np.ceil(np.log2(max(ins_total, 64))))
-        ops, _ = B.compile_local_patches(patches, lmax=args.lmax,
-                                         dmax=args.lmax)
-        block_k = min(args.block_k, capacity // 2)
-        log(f"[northstar] {args.trace}[:{n_ops}] -> {ops.num_steps} steps, "
-            f"capacity {capacity}, batch {batch}, engine {args.engine}")
-        if args.engine == "hbm":
-            run = BH.make_replayer_hbm(ops, capacity=capacity, batch=batch,
-                                       block_k=block_k, chunk=args.chunk,
-                                       interpret=args.interpret)
-            hbm = (2 * capacity + block_k) * batch * 4 \
-                + 2 * ops.num_steps * batch * 4
-        else:
-            run = BL.make_replayer(ops, capacity=capacity, batch=batch,
-                                   block_k=block_k, chunk=args.chunk,
-                                   interpret=args.interpret)
-            hbm = capacity * batch * 4 + 2 * ops.num_steps * batch * 4
-        to_flat = BL.blocked_to_flat
+        block_k = 128
+        capacity = args.capacity or 20992  # RUN rows, not chars
+        capacity = ((capacity + block_k - 1) // block_k) * block_k
+        maker = partial(R.make_replayer_rle, block_k=block_k)
+    log(f"[northstar] {args.trace}[:{n_ops}] -> {ops.num_steps} merged "
+        f"steps, capacity {capacity} runs, batch {batch} x {groups} "
+        f"group(s), engine {args.engine}")
+    run = maker(stream, capacity=capacity, batch=batch,
+                chunk=args.chunk, interpret=args.interpret)
+    hbm = groups * (2 * capacity * batch * 4
+                    + 2 * ops.num_steps * batch * 4)
+    if groups > 1:
+        def to_flat(ops_, res_list):
+            # Verify EVERY group's doc 0 (identical streams).
+            docs = [R.rle_to_flat(ops_, r) for r in res_list]
+            for d in docs[1:]:
+                assert SA.to_string(d) == SA.to_string(docs[0])
+            return docs[0]
+    else:
+        to_flat = R.rle_to_flat
     res, wall, dist = time_run(run, args.reps)
     got = SA.to_string(to_flat(ops, res))
     ok = got == want
     if not ok and not args.lax_check:
         raise AssertionError("northstar replay diverged from string oracle")
-    groups = getattr(args, "groups", 1) if args.engine.startswith("rle") \
-        else 1
     steps = ops.num_steps * max(groups, 1)
     fuse_extra = {}
     if fstats is not None:
@@ -779,10 +752,7 @@ def cfg_3(args):
 def cfg_4(args):
     """Config 4: N-peer concurrent-insert storm (tiebreak-heavy remote
     ops) on the mixed RLE run engine (`doc.rs:242-348` on run rows —
-    the r3 verdict's missing #1). ``--engine blocked-mixed`` selects the
-    round-3 per-char engine for comparison."""
-    from text_crdt_rust_tpu.ops import blocked as BL
-    from text_crdt_rust_tpu.ops import blocked_mixed as BM
+    the r3 verdict's missing #1)."""
     from text_crdt_rust_tpu.ops import rle as R
     from text_crdt_rust_tpu.ops import rle_mixed as RM
 
@@ -797,7 +767,7 @@ def cfg_4(args):
                                    dmax=16)
     total_chars = n_peers * rounds * run_len
     # Suite-wide --engine values cfg_4 doesn't distinguish (rle-hbm,
-    # blocked, ...) fall back to the default run engine rather than
+    # rle-lanes, ...) fall back to the default run engine rather than
     # failing the whole config.
     def run_storm_rle_mixed(config, ops_, want_, n_ops_, base_ops_,
                             batch4, **extra):
@@ -817,23 +787,6 @@ def cfg_4(args):
                         ops_.num_steps, 2 * capacity * batch4 * 4,
                         base_ops_, got == want_,
                         peers=n_peers, rounds=rounds, **extra, **dist)
-
-    if args.engine == "blocked-mixed":
-        # The per-char blocked engine is VMEM-bound at 128 lanes.
-        batch4 = min(args.batch, 128) if args.batch else 128
-        capacity = 2 << int(np.ceil(np.log2(max(total_chars, 256))))
-        block_k = min(256, capacity // 2)
-        run = BM.make_replayer_mixed(ops, capacity=capacity, batch=batch4,
-                                     block_k=block_k,
-                                     chunk=128 if args.smoke else 1024,
-                                     interpret=args.interpret)
-        res, wall, dist = time_run(run, args.reps)
-        got = SA.to_string(BL.blocked_to_flat(ops, res))
-        return make_row("config4_concurrent_insert_storm",
-                        "blocked-mixed", total_chars, batch4, wall,
-                        ops.num_steps, 2 * capacity * batch4 * 4,
-                        base_ops, got == want,
-                        peers=n_peers, rounds=rounds, **dist)
 
     # The run engine's planes (~9.6k rows) fit 512 lanes — and its step
     # cost is dominated by lane-independent sequencing (scalar table
@@ -1664,7 +1617,6 @@ def main() -> None:
                     help="identical-doc lanes (0 = per-config default: "
                          "northstar 512 at capacity <= 20992 else 256, "
                          "others 128)")
-    ap.add_argument("--lmax", type=int, default=16)
     ap.add_argument("--engine", choices=ENGINE_CHOICES, default="rle")
     ap.add_argument("--groups", type=int, default=1,
                     help="northstar doc groups (rle engines; docs = "
@@ -1685,7 +1637,6 @@ def main() -> None:
                     help="rle engine run-row capacity (0 = default 20992 "
                          "for rle, 32768 for rle-hbm; rounded up to a "
                          "block_k multiple)")
-    ap.add_argument("--block-k", type=int, default=512)
     ap.add_argument("--lanes-block-k", type=int, default=64,
                     help="K (rows per block) for the blocked per-lane "
                          "engines, configs 5/5r")

@@ -198,7 +198,13 @@ def cell_serve_pair():
     streams replayed through the kernel-exact blocked cost model, sims
     re-seeded from the oracle at every residency upload exactly as the
     device backend is)."""
-    import blocked_lanes_sim as BLS
+    from blocked_lanes_sim import (
+        BlockedLaneSim,
+        Counter,
+        UnblockedCost,
+        _replay_stream,
+        _seed_sim_from_oracle,
+    )
 
     from text_crdt_rust_tpu.config import ServeConfig, lane_block_geometry
     from text_crdt_rust_tpu.serve.loadgen import ServeLoadGen
@@ -212,15 +218,15 @@ def cell_serve_pair():
                       **SERVE_SHAPE)
     gen = ServeLoadGen(cfg=cfg, **SMALL_LOADGEN)
 
-    c = BLS.Counter()
-    unb = BLS.UnblockedCost(base.lane_capacity)
+    c = Counter()
+    unb = UnblockedCost(base.lane_capacity)
     sims = {}
 
     def tap(doc_id, ops):
         sim = sims.get(doc_id)
         if sim is None:
-            sim = sims[doc_id] = BLS.BlockedLaneSim(K, cap_runs, c, OCAP)
-        BLS._replay_stream(sim, unb, c, ops)
+            sim = sims[doc_id] = BlockedLaneSim(K, cap_runs, c, OCAP)
+        _replay_stream(sim, unb, c, ops)
 
     gen.server.batcher.step_trace = tap
     res = gen.server.residency
@@ -230,9 +236,9 @@ def cell_serve_pair():
                 doc_id = res.lane_owner[si][b]
                 sim = sims.get(doc_id)
                 if sim is None:
-                    sim = sims[doc_id] = BLS.BlockedLaneSim(
+                    sim = sims[doc_id] = BlockedLaneSim(
                         K, cap_runs, c, OCAP)
-                BLS._seed_sim_from_oracle(sim, oracle)
+                _seed_sim_from_oracle(sim, oracle)
                 orig(b, oracle, ranks)
             return upload
         backend.upload_lane = wrap(backend.upload_lane, si)

@@ -128,20 +128,3 @@ def pytest_runtest_makereport(item, call):
         "flight-recorder (TCR_TRACE_DIR)",
         "\n".join(bundles) if bundles
         else f"no post-mortem bundles under {tdir} from this session"))
-
-
-def pytest_collection_modifyitems(config, items):
-    """Deselect ``archival`` suites (superseded-engine differential
-    references) unless the -m expression names them explicitly.  A
-    collection hook instead of an ``addopts -m`` default: a user-passed
-    ``-m slow`` would silently REPLACE the addopts expression and
-    re-admit the archival suites (review r5)."""
-    expr = config.getoption("-m") or ""
-    if "archival" in expr:
-        return
-    keep, drop = [], []
-    for item in items:
-        (drop if "archival" in item.keywords else keep).append(item)
-    if drop:
-        config.hook.pytest_deselected(items=drop)
-        items[:] = keep

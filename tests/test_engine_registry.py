@@ -79,8 +79,7 @@ def test_readme_engine_table_is_registered():
 def test_engines_for_covers_streaming_configs():
     assert "rle-lanes" in engines_for("5")
     assert "rle-lanes-mixed" in engines_for("5r")
-    assert set(engines_for("northstar")) == {"rle", "rle-hbm", "blocked",
-                                             "hbm"}
+    assert set(engines_for("northstar")) == {"rle", "rle-hbm"}
 
 
 def test_lane_block_geometry_rounds_up():

@@ -1,7 +1,6 @@
 """RLE run-blocked engine vs the flat engine and string oracle.
 
-Interpreter-mode differential tests in the ``test_blocked_hbm`` mold:
-tiny blocks (block_k as low as 8 RUNS) force constant leaf SPLITS — the
+Interpreter-mode differential tests: tiny blocks (block_k as low as 8 RUNS) force constant leaf SPLITS — the
 engine's replacement for the global rebalance — so the logical-block-order
 machinery is exercised on every few ops, the analog of the reference's
 shrunken debug node sizes (`range_tree/mod.rs:29-39`). Streams are

@@ -2,9 +2,9 @@
 
 Interpreter-mode differential tests. Tiny blocks (block_k=8) force leaf
 SPLITS between remote lookups, exercising the order index's upkeep
-through splits on the run representation; the scenarios mirror
-``test_blocked_mixed`` (the `doc.rs:242-348` apply paths) plus the
-config-4 concurrent-insert storm and cross-engine local equality with
+through splits on the run representation; the scenarios cover the
+`doc.rs:242-348` apply paths (root tiebreak, two-peer and N-peer merges,
+fragmented, double and long remote deletes) plus the config-4 concurrent-insert storm and cross-engine local equality with
 ``ops.rle``, the kernel's integrate and lookup counters, and both paths
 of the remote delete.
 """
@@ -55,10 +55,6 @@ def oracle_txns(txns):
     return doc
 
 
-# The scenario classes are a heavy interpret-mode matrix: slow tier.
-# Tier-1 keeps rle-mixed coverage via test_rle_mixed_fast.TestTier1Smoke,
-# the blocked-lanes fuzz and ``TestIntegrateCounters`` below.
-@pytest.mark.slow
 class TestMixedRleLocal:
     def test_local_stream_matches_rle(self):
         # KIND_LOCAL handling must stay bit-identical to ops.rle.
@@ -76,7 +72,6 @@ class TestMixedRleLocal:
         assert SA.doc_spans(doc) == SA.doc_spans(ref_doc)
 
 
-@pytest.mark.slow
 class TestMixedRleRemote:
     def test_concurrent_root_inserts_tiebreak(self):
         # Config-4 storm shape: peers insert at the same point with the

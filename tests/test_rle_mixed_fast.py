@@ -55,15 +55,11 @@ def assert_fast_exact(txns, capacity=512):
                           np.asarray(serial.signed))
 
 
-@pytest.mark.slow
 class TestTier1Smoke:
-    """Representative of the fast-vs-serial property (the full matrix
-    below also runs under ``-m slow``): one small storm with deletes
-    through both scan paths, bit-identical and oracle-equal.  Demoted
-    from tier-1 (PR 17 wall budget: ~47 s, the suite was brushing the
-    870 s gate timeout); the fast scan path keeps tier-1 coverage
-    through the ``test_rle_lanes_mixed`` tiling/growth suites and the
-    serve-lanes backend tests, which drive the same engine."""
+    """The fast-vs-serial property at its smallest: one small storm with
+    deletes through both scan paths, bit-identical and oracle-equal.  A
+    failure here names the property before the matrix below runs its
+    larger shapes."""
 
     def test_small_delete_storm(self):
         txns, receiver = make_storm(3, 4, 2, seed=7, del_prob=0.3)
@@ -76,7 +72,6 @@ class TestTier1Smoke:
                               np.asarray(serial.signed))
 
 
-@pytest.mark.slow
 class TestFastIntegrate:
     def test_insert_storm(self):
         # The config-4 shape: every window run is a ROOT-origin sibling.

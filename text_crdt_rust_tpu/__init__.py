@@ -8,8 +8,8 @@ Layout (see each subpackage's __init__ for what is implemented):
 - ``models/``    document engines: Python oracle, C++ native engine
                  (ctypes), peer sync;
 - ``ops/``       device kernels: the RLE run engines (``rle`` /
-                 ``rle_hbm`` / ``rle_lanes``), per-char engines
-                 (``flat`` / ``blocked*``), the op compiler (``batch``);
+                 ``rle_hbm`` / ``rle_lanes`` / ``rle_mixed``), the
+                 per-char ``flat`` engine, the op compiler (``batch``);
 - ``parallel/``  mesh sharding (dp/sp) + the causal buffer;
 - ``utils/``     RLE span algebra, trace loader, metrics, checkpoint;
 - ``native/``    C++ sources + build;

@@ -41,9 +41,6 @@ ENGINE_REGISTRY = {
                         "fused_steps": True,
                         "serve_backend":
                             "serve.lanes_backend:LanesMixedLaneBackend"},
-    "blocked":         {"module": "ops.blocked", "configs": ("northstar",)},
-    "blocked-mixed":   {"module": "ops.blocked_mixed", "configs": ("4",)},
-    "hbm":             {"module": "ops.blocked_hbm", "configs": ("northstar",)},
     # The serve batcher's device backends: ``serve_backend`` names the
     # LaneBackend class `serve.batcher.make_lane_backend` constructs —
     # registry-driven dispatch, no hardcoded engine asserts.  The

@@ -29,10 +29,15 @@ see ``span_arrays``) and cross-check bit-identically in ``tests/``:
                   stream — the production sync shape; config 5's remote
                   variant), with per-lane by-order origin tables and a
                   lane-vectorized YATA scan.
-- ``blocked`` / ``blocked_hbm`` — the round-2 per-character block
-                  engines (kept as references and for the unmerged-stream
-                  path); ``blocked_mixed`` adds the remote-op hot path
-                  in-kernel on char rows (superseded by ``rle_mixed``).
+
+Which engine runs where:
+
+- the benchmark's ``replay-automerge-fleet`` cell runs ``rle``, and its
+  ``merge-concurrent-fleet`` cell runs ``rle_mixed`` (its remote txns
+  compiled by ``batch.compile_remote_txns``);
+- the document server's two lane backends (``ServeConfig.engine``) run
+  ``flat`` (the default, ``serve.batcher.FlatLaneBackend``) and
+  ``rle_lanes_mixed`` (``serve.lanes_backend.LanesMixedLaneBackend``).
 
 ``batch`` compiles editing traces into fixed-shape op tensors (the
 host-side analog of the reference's bench replay loop,

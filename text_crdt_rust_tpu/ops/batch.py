@@ -44,7 +44,7 @@ from ..obs.trace import span
 from ..utils.rle import KOrderSpan, Rle
 from ..utils.testdata import TestData, TestPatch, flatten_patches
 
-# Op kinds (device-side dispatch in ops.flat / ops.blocked).
+# Op kinds (device-side dispatch in ops.flat / ops.rle_mixed).
 KIND_LOCAL = 0        # delete del_len live chars at pos, then insert at pos
 KIND_REMOTE_INS = 1   # YATA-integrate an insert run at resolved origins
 KIND_REMOTE_DEL = 2   # tombstone an order-contiguous target range
@@ -363,6 +363,13 @@ def fused_engine_names() -> Tuple[str, ...]:
                  if spec.get("fused_steps"))
 
 
+def _require(cond: bool, msg: str) -> None:
+    """Config/capacity precheck that must fire even under ``python -O``
+    (a violated precondition corrupts device state silently, no crash)."""
+    if not cond:
+        raise ValueError(msg)
+
+
 def require_unfused(ops: OpTensors, engine: str) -> None:
     """The ONE reject guard for engines without the W-row splice (every
     engine without a registry ``fused_steps`` flag calls this at build
@@ -424,7 +431,7 @@ def compile_local_patches(
     Returns ``(ops, next_order)``. Each patch deletes then inserts at
     ``pos`` (`doc.rs:392-464` op order: delete ops take the earlier order
     numbers, then the insert run). ``dmax`` additionally chunks deletes
-    (the blocked engine bounds per-step delete spans; the flat engine's
+    (for an engine that bounds per-step delete spans; the flat engine's
     live-rank window op handles any span, so None = unchunked).
 
     ``fuse_w > 1`` enables SPLIT-BATCH PREPARE: a backwards-contiguous
@@ -874,7 +881,7 @@ def compile_remote_txns(
     The ``assigner`` carries the peer-local order metadata between calls
     (streaming apply); txns must arrive causally ready — buffering
     out-of-order arrivals is ``parallel.causal``'s job. ``dmax`` chunks
-    remote delete target runs (the blocked mixed engine bounds per-step
+    remote delete target runs (for an engine that bounds per-step
     targets; the flat engine masks whole order ranges, so None there).
     """
     assert dmax is None or dmax >= 1, f"dmax must be >= 1, got {dmax}"

@@ -30,7 +30,7 @@ equivalent paths (bit-identical, pinned by ``tests/test_device_prefill.py``):
 
 - **host prefill** (``batch.prefill_logs``): materialize the logs host-side,
   scatter with numpy, re-upload — the build-time path the replay engines
-  (``ops.rle``/``ops.blocked``/``parallel.mesh``) use, where the doc is
+  (``ops.rle``/``ops.rle_mixed``/``parallel.mesh``) use, where the doc is
   being constructed on host anyway;
 - **device-resident delta prefill** (``batch.prefill_delta`` +
   ``apply_prefill_delta``, ISSUE 14): ship only the fixed-shape padded
@@ -481,7 +481,7 @@ def apply_ops(doc: FlatDoc, ops: OpTensors, prefill: bool = True) -> FlatDoc:
 
     - ``prefill=True`` (default) runs the HOST path, ``batch.
       prefill_logs`` — what the build-time replay engines (``ops.rle``/
-      ``ops.blocked``/``parallel.mesh``) and one-shot callers use;
+      ``ops.rle_mixed``/``parallel.mesh``) and one-shot callers use;
     - ``prefill=False`` + caller-managed prefill: either the logs were
       already host-prefilled for this stream (e.g. re-running it), or
       the caller applied the DEVICE path first — ``apply_prefill_delta

@@ -36,7 +36,7 @@ instead of 259,778 steps over 524k rows:
   round-2 engines off the pure-prepend worst case (`benches/yjs.rs:51-62`);
 - documents batch in the lane dimension (identical-stream lanes), and
   divergent doc GROUPS ride a leading grid dimension exactly like
-  ``ops.blocked_hbm`` (config-3 ragged corpus shape);
+  ``ops.rle_hbm`` (config-3 ragged corpus shape);
 - control scalars and single rows are read as one-row loads
   (``ref[pl.ds(row, 1), :]``): only the descent and the delete's cover
   counts reduce over a whole plane.
@@ -67,17 +67,46 @@ from ..obs.trace import span
 from .batch import (
     KIND_LOCAL,
     OpTensors,
+    _require,
     fused_width_checked,
     merge_fused_origins,
     prefill_logs,
 )
-from .blocked import _cumsum_rows, _lane_scalar, _require, _shift_rows
 from .span_arrays import FlatDoc, I32, U32, make_flat_doc
+
+
+def _lane_scalar(x2d) -> jax.Array:
+    """Row-sum then lane-max: collapse a lane-replicated [rows, B] value to
+    one scalar. Valid because every doc (lane) replays the same stream, so
+    all lanes hold identical control state."""
+    return jnp.max(jnp.sum(x2d, axis=0))
+
+
+def _cumsum_rows(x) -> jax.Array:
+    """Inclusive cumsum along the (sublane) row axis via log2 roll-adds."""
+    n = x.shape[0]
+    row = lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    out = x
+    shift = 1
+    while shift < n:
+        out = out + jnp.where(row >= shift, pltpu.roll(out, shift, axis=0), 0)
+        shift *= 2
+    return out
+
+
+def _shift_rows(x, amount, max_amount: int) -> jax.Array:
+    """Rows shifted toward higher indices by dynamic ``amount``
+    (0..max_amount) — one static roll per bit (``flat._shift_right``)."""
+    out = x
+    for b in range(max(max_amount, 1).bit_length()):
+        out = jnp.where((amount >> b) & 1 != 0,
+                        pltpu.roll(out, 1 << b, axis=0), out)
+    return out
 
 
 def _shift_rows_up(x, amount, max_amount: int) -> jax.Array:
     """Rows shifted toward LOWER indices by dynamic ``amount`` (the
-    mirror of ``blocked._shift_rows``): out[j] = x[j + amount]."""
+    mirror of ``_shift_rows``): out[j] = x[j + amount]."""
     out = x
     n = x.shape[0]
     for b in range(max(max_amount, 1).bit_length()):
@@ -600,7 +629,7 @@ def make_replayer_rle(
 ):
     """Build a jitted replayer for one local-edit stream (or a SEQUENCE
     of streams — divergent doc groups on a leading grid dimension, the
-    ``blocked_hbm`` group contract).
+    group contract ``ops.rle_hbm`` shares).
 
     ``capacity`` counts RUN ROWS, not characters: automerge-paper peaks
     at 13,218 rows (vs 524,288 char rows) — compile the stream with
@@ -616,7 +645,7 @@ def make_replayer_rle(
                  "streams (no per-lane batching inside a group)")
         _require(bool((kinds == KIND_LOCAL).all()),
                  "rle engine replays local streams; remote ops -> "
-                 "ops.blocked_mixed / ops.flat")
+                 "ops.rle_mixed / ops.flat")
     _require(capacity % block_k == 0,
              f"capacity ({capacity}) must be a multiple of block_k "
              f"({block_k})")

@@ -37,14 +37,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..common import ROOT_ORDER
-from .batch import KIND_LOCAL, fused_width_checked
-from .blocked import _cumsum_rows, _require, _shift_rows
+from .batch import KIND_LOCAL, _require, fused_width_checked
 from .rle import (
     RleResult,
+    _cumsum_rows,
     _delete_block_math,
     _insert_splice,
     _locate_run,
     _row_scalar,
+    _shift_rows,
     _shift_rows_up,
 )
 
@@ -346,7 +347,7 @@ def make_replayer_rle_hbm(
                  "streams")
         _require(bool((kinds == KIND_LOCAL).all()),
                  "rle_hbm engine replays local streams; remote ops -> "
-                 "ops.blocked_mixed / ops.flat")
+                 "ops.rle_mixed / ops.flat")
     _require(capacity % block_k == 0,
              f"capacity ({capacity}) must be a multiple of block_k "
              f"({block_k})")

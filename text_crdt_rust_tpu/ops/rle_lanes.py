@@ -45,12 +45,12 @@ from ..common import ROOT_ORDER
 from .batch import (
     KIND_LOCAL,
     OpTensors,
+    _require,
     fused_width,
     fused_width_checked,
     merge_fused_origins,
     prefill_logs,
 )
-from .blocked import _require
 from .rle import fused_splice_rows
 from .span_arrays import FlatDoc, I32, U32, make_flat_doc
 

@@ -66,10 +66,10 @@ from .batch import (
     KIND_REMOTE_INS,
     OpTensors,
     _prefill_scatter,
+    _require,
     fused_width,
     fused_width_checked,
 )
-from .blocked import _require
 from .rle import fused_splice_rows
 from .rle_lanes import (
     LanesResult,

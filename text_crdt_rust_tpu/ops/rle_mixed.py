@@ -2,9 +2,9 @@
 
 ``ops.rle`` is the north-star local-replay engine: device state is the
 RLE run (`src/list/span.rs:6-119` semantics, ~40x fewer rows than chars)
-but it refuses remote ops.  ``ops.blocked_mixed`` applies remote ops
-(YATA integrate, `doc.rs:167-234`) but on ONE ROW PER CHARACTER.  This
-engine is the round-4 unification the r3 verdict demanded: the full op
+but it refuses remote ops.  The round-3 engine that first applied
+remote ops (YATA integrate, `doc.rs:167-234`) held ONE ROW PER
+CHARACTER.  This engine is the round-4 unification: the full op
 surface — KIND_LOCAL, KIND_REMOTE_INS, KIND_REMOTE_DEL — applied
 directly to the run representation, so the `doc.rs:242-348` hot path
 (the reference's raison d'etre) runs on state that is runs, not chars.
@@ -64,16 +64,19 @@ from .batch import (
     KIND_REMOTE_DEL,
     KIND_REMOTE_INS,
     OpTensors,
+    _require,
     prefill_logs,
     require_unfused,
 )
-from .blocked import _cumsum_rows, _lane_scalar, _require, _shift_rows
 from .rle import (
     RleResult,
+    _cumsum_rows,
     _delete_block_math,
     _insert_splice,
+    _lane_scalar,
     _locate_run,
     _row_scalar,
+    _shift_rows,
     _shift_rows_up,
     _split_piece_aux,
 )

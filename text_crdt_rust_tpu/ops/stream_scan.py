@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .blocked import _require
+from .batch import _require
 
 
 @jax.jit
