@@ -20,8 +20,9 @@ from text_crdt_rust_tpu.utils.randedit import make_storm
 
 
 def replay_both(txns, capacity, block_k=8, lmax=4, chunk=128, dmax=None):
-    """(fast_flat, serial_flat) for one txn stream; tiny blocks force
-    splits so the aux planes' motion paths are all exercised."""
+    """[(flat, integrate_counts)] of the fast run, then the serial, for
+    one txn stream; tiny blocks force splits so the aux planes' motion
+    paths are all exercised."""
     table = B.AgentTable()
     for t in txns:
         table.add(t.id.agent)
@@ -35,7 +36,7 @@ def replay_both(txns, capacity, block_k=8, lmax=4, chunk=128, dmax=None):
                                   block_k=block_k, chunk=chunk,
                                   interpret=True, fast_integrate=fast)
         res.check()
-        outs.append(R.rle_to_flat(ops, res))
+        outs.append((R.rle_to_flat(ops, res), res.integrate_counts()))
     return outs
 
 
@@ -47,12 +48,28 @@ def oracle_txns(txns):
 
 
 def assert_fast_exact(txns, capacity=512):
-    fast, serial = replay_both(txns, capacity)
+    """Fast == serial == oracle; returns the (fast, serial) runs'
+    ``integrate_counts``."""
+    (fast, fast_counts), (serial, serial_counts) = replay_both(
+        txns, capacity)
     want = oracle_txns(txns).to_string()
     assert SA.to_string(serial) == want
     assert SA.to_string(fast) == want
     assert np.array_equal(np.asarray(fast.signed),
                           np.asarray(serial.signed))
+    return fast_counts, serial_counts
+
+
+def typed(name, see, edit):
+    """The txns ``name`` exports after seeing ``see`` and making
+    ``edit(doc, agent)``."""
+    doc = ListCRDT()
+    agent = doc.get_or_create_agent_id(name)
+    for t in see:
+        doc.apply_remote_txn(t)
+    m = doc.get_next_order()
+    edit(doc, agent)
+    return export_txns_since(doc, m)
 
 
 class TestTier1Smoke:
@@ -63,7 +80,7 @@ class TestTier1Smoke:
 
     def test_small_delete_storm(self):
         txns, receiver = make_storm(3, 4, 2, seed=7, del_prob=0.3)
-        fast, serial = replay_both(txns, capacity=256, chunk=32)
+        (fast, _), (serial, _) = replay_both(txns, capacity=256, chunk=32)
         want = oracle_txns(txns).to_string()
         assert want == receiver.to_string()
         assert SA.to_string(serial) == want
@@ -159,15 +176,6 @@ class TestFastIntegrate:
         # sibling probing a window that contains the split tail must
         # classify it exactly (same tiebreak outcome as the serial
         # walk and the oracle), in both causal orders.
-        def typed(name, see, edit):
-            doc = ListCRDT()
-            agent = doc.get_or_create_agent_id(name)
-            for t in see:
-                doc.apply_remote_txn(t)
-            m = doc.get_next_order()
-            edit(doc, agent)
-            return export_txns_since(doc, m)
-
         # mmm types "ab", APPENDS "cd" (merge-appends into one run
         # [abcd]; c's table origin-right is ROOT, the head's is not),
         # then SPLITS at 2 with "Q" -> [ab][Q][cd].  The tail [cd]'s
@@ -197,15 +205,6 @@ class TestFastIntegrate:
         # origin-right sibling (kss).  kss was reduced against the
         # pre-pseudo kfb; the winner must be the pseudo's cursor0, not
         # the stale kss run.
-        def typed(name, see, edit):
-            doc = ListCRDT()
-            agent = doc.get_or_create_agent_id(name)
-            for t in see:
-                doc.apply_remote_txn(t)
-            m = doc.get_next_order()
-            edit(doc, agent)
-            return export_txns_since(doc, m)
-
         t1 = typed("mmm", [], lambda d, g: d.local_insert(g, 0, "X"))
         t2 = typed("mmm", t1, lambda d, g: d.local_insert(g, 1, "Y"))
         # ppp saw only X: W after X (ol=X, or=ROOT) — my SGO window run.
@@ -218,3 +217,55 @@ class TestFastIntegrate:
         # into X's run, rank mmm > aaa, or ROOT == mine -> break).
         t5 = typed("aaa", t1, lambda d, g: d.local_insert(g, 1, "a"))
         assert_fast_exact([*t1, *t2, *t3, *t4, *t5], capacity=256)
+
+    def test_root_origin_window_of_descendants_is_fast(self):
+        # The crdt-benchmarks B2.4 shape: two clients edit from empty,
+        # neither seeing the other, and the server applies A's update,
+        # then B's.  A types at random positions, so its document holds
+        # descendants and split tails (runs neither siblings nor chained
+        # pieces); B's first inserts go at position 0, ROOT origin-left,
+        # so each scans all of A's text.  left_cursor is 0 there and no
+        # such run can break the walk: the fast scan takes the window
+        # whole and no insert runs the serial walk.
+        rng = random.Random(0)
+
+        def history(name, rounds, front):
+            def edit(doc, agent):
+                n = 0
+                for r in range(rounds):
+                    pos = 0 if r < front else rng.randint(0, n)
+                    word = "".join(rng.choice("abcdefgh")
+                                   for _ in range(rng.randint(1, 3)))
+                    doc.local_insert(agent, pos, word)
+                    n += len(word)
+                    if rng.random() < 0.3 and n > pos:
+                        d = rng.randint(1, min(2, n - pos))
+                        doc.local_delete(agent, pos, d)
+                        n -= d
+            return typed(name, [], edit)
+
+        txns = [*history("amy", 12, 0), *history("bob", 4, 3)]
+        fast_counts, serial_counts = assert_fast_exact(txns)
+        assert fast_counts[0] > 0
+        assert fast_counts[1] == 0
+        assert serial_counts == (fast_counts[0], fast_counts[0])
+
+    def test_non_root_origin_break_still_flags(self):
+        # The classic YATA break: aaa types "a"; bbb and ccc each put a
+        # char after it (siblings, bbb ranks lower so "abc"); ddd saw
+        # "ab" only and puts "d" after b.  d's window holds c, whose
+        # origin-left a lies BEFORE d's (olc < left_cursor): the serial
+        # walk stops there, so d lands before c.  Only a ROOT origin-left
+        # rules that out, so this window must still run the exact walk.
+        # c arrives before b: its order follows a's, so only the check
+        # that its predecessor sits in the previous row keeps it from
+        # passing for a chained piece.
+        t1 = typed("aaa", [], lambda d, g: d.local_insert(g, 0, "a"))
+        t2 = typed("bbb", t1, lambda d, g: d.local_insert(g, 1, "b"))
+        t3 = typed("ccc", t1, lambda d, g: d.local_insert(g, 1, "c"))
+        t4 = typed("ddd", [*t1, *t2],
+                   lambda d, g: d.local_insert(g, 2, "d"))
+        stream = [*t1, *t3, *t2, *t4]
+        assert oracle_txns(stream).to_string() == "abdc"
+        fast_counts, _ = assert_fast_exact(stream, capacity=256)
+        assert fast_counts[1] >= 1

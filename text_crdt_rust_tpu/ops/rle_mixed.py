@@ -657,7 +657,13 @@ def _mixed_rle_kernel(
         ``olc == left_cursor`` exactly) or a PHYSICALLY-CHAINED piece
         (head chains to its own predecessor char, which sits in the
         previous row of the same block — then ``olc == head position >=
-        left_cursor``, the serial walk's plain advance).  Anything else
+        left_cursor``, the serial walk's plain advance).  When the op's
+        ``origin_left`` is ROOT any other run is a plain advance too:
+        ``left_cursor`` is 0, and a non-sibling's origin_left is a char
+        (only siblings have ROOT there), so its ``olc`` is at least 1
+        and the serial walk neither breaks nor ties on it; a run holding
+        a mid-run ``o_right`` lands the cursor on ``tpos``, as the
+        serial walk's ``contains_right`` step does.  Anything else
         — including a split piece whose by-order predecessor was
         spliced away from it — raises ``flag`` and the caller falls
         back to the exact serial loop, so exotic windows lose speed,
@@ -684,6 +690,7 @@ def _mixed_rle_kernel(
         key_hi = jnp.where(tpos >= n, BIGK,
                            lT * K + iT + jnp.where(offT == 0, 0, 1))
         l_end = jnp.where(tpos >= n, meta[0] - 1, lT)
+        root_left = o_left == root_i
 
         def classify(l, carry):
             kfb, kll, kss, bad_any = carry
@@ -702,7 +709,8 @@ def _mixed_rle_kernel(
             e_prev = pltpu.roll(h + blk(lenp, b) - 1, 1, axis=0)
             chain = (W & ~S & (h > 0) & (ao == h - 1)
                      & (idx_k > 0) & (e_prev == h - 1))
-            bad = (W & ~S & ~chain) | (S & ((ak == my_rank) | (ar == -2)))
+            bad = ((W & ~S & ~chain & ~root_left)
+                   | (S & ((ak == my_rank) | (ar == -2))))
             gt_r = ak > my_rank
             sgo = S & gt_r & (ar == o_right)
             sgn = S & gt_r & (ar != o_right)
