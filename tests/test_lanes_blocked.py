@@ -299,3 +299,87 @@ class TestBlockedMixedLanes:
             stacked, capacity=8, block_k=8, chunk=8, interpret=True)
         with pytest.raises(RuntimeError, match="lanes \\[0\\]"):
             res.check()
+
+
+# ---------------------------------------------------------------------------
+# A fleet of distinct real documents: the first 300 patches of each of the
+# three editing traces, 128 lanes in a seeded three-trace map, ragged
+# stacked streams (the replay-divergent-fleet cell at a test's size).
+# ---------------------------------------------------------------------------
+
+TRACES = ("automerge-paper", "rustcode", "sveltecomponent")
+PREFIX = 300
+
+
+def fleet_map(seed: int = 2029):
+    lanes = [0] * 43 + [1] * 43 + [2] * 42
+    random.Random(seed).shuffle(lanes)
+    return lanes
+
+
+@pytest.fixture(scope="module")
+def trace_fleet():
+    from text_crdt_rust_tpu.utils import load_testing_data, trace_path
+
+    prefixes, fused, unfused = [], [], []
+    for name in TRACES:
+        data = load_testing_data(trace_path(name))
+        patches = [p for t in data.txns for p in t.patches][:PREFIX]
+        prefixes.append(patches)
+        merged = B.merge_patches(patches)
+        ops, _ = B.compile_local_patches(merged, lmax=8192, dmax=None)
+        unfused.append(ops)
+        fused.append(B.fuse_steps(ops, fuse_w=8)[0])
+    oracles = [oracle_signed(oracle_from_patches(p)) for p in prefixes]
+    return dict(lanes=fleet_map(), fused=fused, unfused=unfused,
+                oracles=oracles)
+
+
+def fleet_replay(fleet, which, **kw):
+    streams = fleet[which]
+    stacked = B.stack_ops([streams[t] for t in fleet["lanes"]],
+                          chars=False)
+    run = RL.make_replayer_lanes_blocked(stacked, interpret=True, **kw)
+    res = run()
+    res.check()
+    return run, res
+
+
+class TestEditingTraceFleet:
+    GEOMETRY = dict(capacity=1024, block_k=32, chunk=64)
+
+    def test_every_lane_is_its_own_trace(self, trace_fleet):
+        _, res = fleet_replay(trace_fleet, "fused", **self.GEOMETRY)
+        lens = {int(s.num_steps) for s in trace_fleet["fused"]}
+        assert len(lens) == 3, "the traces' streams must be ragged"
+        for b, t in enumerate(trace_fleet["lanes"]):
+            assert (RL.expand_lane(res, b).tolist()
+                    == trace_fleet["oracles"][t]), f"lane {b}"
+
+    def test_fused_and_unfused_stacks_agree(self, trace_fleet):
+        assert any(B.fused_width(s) > 1 for s in trace_fleet["fused"])
+        _, fused = fleet_replay(trace_fleet, "fused", **self.GEOMETRY)
+        _, plain = fleet_replay(trace_fleet, "unfused", **self.GEOMETRY)
+        for b in range(128):
+            assert (RL.expand_lane(fused, b).tolist()
+                    == RL.expand_lane(plain, b).tolist()), f"lane {b}"
+
+    def test_lane_steps_count_real_and_stepped(self, trace_fleet):
+        run, _ = fleet_replay(trace_fleet, "fused", **self.GEOMETRY)
+        streams = [trace_fleet["fused"][t] for t in trace_fleet["lanes"]]
+        s_max = max(int(s.num_steps) for s in streams)
+        s_pad = -(-s_max // 64) * 64
+        assert run.lane_steps == RL.LaneSteps(
+            real=sum(int(s.num_steps) for s in streams),
+            stepped=s_pad * 128)
+        assert run.lane_steps.real < run.lane_steps.stepped
+
+    def test_many_blocks(self, trace_fleet):
+        # 96 blocks of 8 rows: the loop over the blocks runs far past
+        # the tiny geometries, and the longest prefix fills dozens.
+        _, res = fleet_replay(trace_fleet, "fused", capacity=8 * 96,
+                              block_k=8, chunk=64)
+        assert int(np.asarray(res.nlog).max()) > 16
+        for b, t in enumerate(trace_fleet["lanes"]):
+            assert (RL.expand_lane(res, b).tolist()
+                    == trace_fleet["oracles"][t]), f"lane {b}"

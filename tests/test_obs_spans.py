@@ -263,6 +263,44 @@ def test_remote_spans_land_on_the_profilers_host_plane(tmp_path):
         assert max(e for _, e in spans[a]) <= min(s for s, _ in spans[b])
 
 
+def test_lanes_replayer_opens_the_replay_spans(tmp_path):
+    """The per-lane engine (``ops.rle_lanes``'s blocked replayer) at a
+    tiny size: two distinct documents, staged once, built on the first
+    call and dispatched on every later one, on ``/host:CPU`` in that
+    order, as many as ``span_totals`` counts."""
+    import jax
+
+    from text_crdt_rust_tpu.ops import batch as B
+    from text_crdt_rust_tpu.ops import rle_lanes as RL
+    from text_crdt_rust_tpu.utils.testdata import TestPatch
+
+    streams = [B.compile_local_patches(ps, lmax=8)[0] for ps in (
+        [TestPatch(0, 0, "hello"), TestPatch(5, 0, " world")],
+        [TestPatch(0, 0, "abc"), TestPatch(1, 1, "XY")])]
+    stacked = B.stack_ops(streams)
+    reset_span_totals()
+    calls = 3
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        run = RL.make_replayer_lanes_blocked(stacked, capacity=32,
+                                             block_k=8, chunk=8,
+                                             interpret=True)
+        results = [run() for _ in range(calls)]
+        jax.block_until_ready(results[-1].ordp)
+    finally:
+        jax.profiler.stop_trace()
+    got = counts()
+    assert got == {"rle.stage": 1, "rle.build": 1,
+                   "rle.dispatch": calls - 1}
+    assert span_totals()["rle.dispatch"]["cpu_s"] is not None
+    assert len(RL.expand_lane(results[-1], 0)) == len("hello world")
+    spans = _host_spans(tmp_path)
+    assert {k: len(v) for k, v in spans.items()} == got
+    order = ("rle.stage", "rle.build", "rle.dispatch")
+    for a, b in zip(order, order[1:]):
+        assert max(e for _, e in spans[a]) <= min(s for s, _ in spans[b])
+
+
 def test_serve_tick_phases_are_spans():
     """The batcher's drain, dispatch and sync walls go through ``span``:
     the totals count them, and the tracer's events carry the same walls

@@ -12,6 +12,9 @@ no chip time.  Geometries are the ones the chip path launches
 - ``ops.rle_mixed._build_mixed_call`` at the merge cell's shape
   (``benchmark/configs/merge-crdtbench-b24-n6000.json``: B=128, CAP
   46,208, K 128, chunk 1024), which VMEM bounds at 128 lanes;
+- ``ops.rle_lanes._build_blocked_call`` at the divergent fleet's shape
+  (``benchmark/configs/replay-editingtraces-divergent.json``: B=128,
+  CAP 22,656, K 128, chunk 1024);
 - ``ops.rle_lanes_mixed._build_blocked_call`` at the serve lanes
   geometry (B=128, CAP 512, K 32, OCAP 1536, chunk 128);
 - ``ops.flat`` 's tick-train program at one serve train bucket;
@@ -110,6 +113,27 @@ def test_serve_lanes_kernel_compiles(one_chip):
     deltas = [i32(ocap)] * 3
     compiled = jitted.lower(*cols, *state, *deltas).compile()
     assert _kernel_in(compiled)
+
+
+def test_divergent_lanes_kernel_compiles(one_chip):
+    """``ops.rle_lanes``' blocked kernel at the divergent fleet's shape
+    (``benchmark/configs/replay-editingtraces-divergent.json``: 128
+    lanes, 177 blocks of 128 rows, chunk 1024, fused rows up to 3): its
+    loop over the blocks keeps the code small at this NB, and its four
+    single-buffered state planes fit the kernel's VMEM."""
+    from text_crdt_rust_tpu.ops import rle_lanes as RL
+
+    s_pad, B, cap, k, chunk, wmax = 10240, 128, 22656, 128, 1024, 3
+    nbt = cap // k
+    jitted = RL._build_blocked_call(s_pad, B, cap, k, chunk, False, None,
+                                    wmax)
+    i32 = lambda rows: jax.ShapeDtypeStruct((rows, B), jnp.int32,
+                                            sharding=one_chip)
+    compiled = jitted.lower(*[i32(s_pad)] * 5, i32(cap), i32(cap), i32(1),
+                            i32(nbt), i32(nbt), i32(nbt)).compile()
+    assert "rle_lanes_replay" in compiled.as_text()
+    out_bytes = compiled.memory_analysis().output_size_in_bytes
+    assert out_bytes >= 2 * cap * B * 4
 
 
 def test_serve_flat_train_compiles(one_chip):

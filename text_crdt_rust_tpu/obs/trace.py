@@ -57,11 +57,13 @@ kind                 emitted by / meaning
                      by-order logs prefilled (``prefill_logs``) and packed
                      into the kernel's origin and rank tables
 ``rle.stage``        ``ops.rle.make_replayer_rle`` /
-                     ``ops.rle_mixed.make_replayer_rle_mixed``: op
+                     ``ops.rle_mixed.make_replayer_rle_mixed`` /
+                     ``ops.rle_lanes.make_replayer_lanes_blocked``: op
                      columns padded and put on the device
-``rle.build``        an ``ops.rle`` or ``ops.rle_mixed`` replayer's first
-                     call: trace, lower, compile (or compile-cache load),
-                     first enqueue
+``rle.build``        an ``ops.rle``, ``ops.rle_mixed`` or blocked
+                     ``ops.rle_lanes`` replayer's first call: trace,
+                     lower, compile (or compile-cache load), first
+                     enqueue
 ``rle.dispatch``     every later call: kernel enqueue + per-group unpack
                      (wall and thread CPU; the unpack can wait on the
                      device)

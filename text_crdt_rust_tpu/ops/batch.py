@@ -1267,9 +1267,20 @@ def concat_ops(streams: Sequence[OpTensors]) -> OpTensors:
         *streams)
 
 
-def stack_ops(streams: Sequence[OpTensors]) -> OpTensors:
+def stack_ops(streams: Sequence[OpTensors],
+              chars: bool = True) -> OpTensors:
     """Ragged per-doc streams -> one time-major [S, B, ...] tensor batch
-    (config 3's mixed-corpus batch; shorter docs run no-op tail steps)."""
+    (config 3's mixed-corpus batch; shorter docs run no-op tail steps).
+
+    ``chars=False`` stacks the step columns alone, with a zero-width
+    ``chars``: the run engines (``ops.rle_lanes``) read lengths and
+    orders, never codepoints, and a fleet's stacked codepoints would
+    take ``S * B * lmax * 4`` bytes (43 GB at 10,240 steps, 128 lanes
+    and lmax 8,192).  Streams of different ``lmax`` stack then too."""
+    if not chars:
+        streams = [dataclasses.replace(
+            o, chars=np.zeros((o.num_steps, 0), np.uint32))
+            for o in streams]
     s_max = max(o.num_steps for o in streams)
     padded = [pad_ops(o, s_max) for o in streams]
     return jax.tree.map(lambda *xs: np.stack(xs, axis=1), *padded)

@@ -10,16 +10,18 @@ block — O(NB + K) rows instead of O(CAP log CAP).
 
 The per-lane twist vs ``ops.rle``: every block index is a ``[1, B]``
 LANE VECTOR, not a scalar, so blocks cannot be addressed with a dynamic
-slice.  Gather/scatter instead run an NB-way select chain over static
-K-row slices — one plane-read equivalent — and every in-block pass
-(cumsum, splice, 3-way split) then costs K rows, which is where the
-win lives (K << CAP on the config-5/5r shapes).
+slice.  Gather/scatter instead loop over the NB blocks, selecting per
+lane (one plane-read equivalent), and every in-block pass (cumsum,
+splice, 3-way split) then costs K rows, which is where the win lives
+(K << CAP).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
@@ -38,40 +40,56 @@ def vshift_up(x, amt, max_amt: int) -> jax.Array:
     return out
 
 
+def _block(nb, K: int):
+    """Rows of physical block ``nb`` (a loop index): a K-row slice at an
+    aligned dynamic offset."""
+    return pl.ds(pl.multiple_of(nb * K, K), K)
+
+
 def gather_block(plane_ref, b, K: int, NB: int) -> jax.Array:
     """Per-lane block gather: ``out[j, lane] = plane[b[0,lane]*K + j,
-    lane]`` as one NB-way select chain over static K-row slices."""
-    ws = plane_ref[0:K, :]
-    for nb in range(1, NB):
-        ws = jnp.where(b == nb, plane_ref[nb * K:(nb + 1) * K, :], ws)
-    return ws
+    lane]`` as an NB-way select over the K-row blocks.  A loop over the
+    blocks, not a Python unroll: the kernel's code and its compile stay
+    the same size at any NB (the editing traces reach 177 blocks)."""
+    return lax.fori_loop(
+        1, NB,
+        lambda nb, ws: jnp.where(b == nb, plane_ref[_block(nb, K), :], ws),
+        plane_ref[0:K, :])
 
 
 def gather_head(plane_ref, b, K: int, NB: int) -> jax.Array:
     """Row 0 of per-lane block ``b`` as a [1, B] vector."""
-    h = plane_ref[0:1, :]
-    for nb in range(1, NB):
-        h = jnp.where(b == nb, plane_ref[nb * K: nb * K + 1, :], h)
-    return h
+    return lax.fori_loop(
+        1, NB,
+        lambda nb, h: jnp.where(
+            b == nb, plane_ref[pl.ds(pl.multiple_of(nb * K, K), 1), :], h),
+        plane_ref[0:1, :])
 
 
 def scatter_block(plane_ref, b, ws, act, K: int, NB: int) -> None:
     """Write ``ws`` back to per-lane block ``b`` on ``act`` lanes."""
-    for nb in range(NB):
-        cur = plane_ref[nb * K:(nb + 1) * K, :]
-        plane_ref[nb * K:(nb + 1) * K, :] = jnp.where(
-            act & (b == nb), ws, cur)
+
+    def body(nb, c):
+        rows = _block(nb, K)
+        plane_ref[rows, :] = jnp.where(act & (b == nb), ws,
+                                       plane_ref[rows, :])
+        return c
+
+    lax.fori_loop(0, NB, body, 0)
 
 
 def scatter_block2(plane_ref, b1, ws1, b2, ws2, act, K: int,
                    NB: int) -> None:
     """Two-block scatter (block split: keep-half to ``b1``, moved half
     to the fresh block ``b2``; b1 != b2 per lane)."""
-    for nb in range(NB):
-        cur = plane_ref[nb * K:(nb + 1) * K, :]
-        v = jnp.where(act & (b1 == nb), ws1, cur)
-        plane_ref[nb * K:(nb + 1) * K, :] = jnp.where(
-            act & (b2 == nb), ws2, v)
+
+    def body(nb, c):
+        rows = _block(nb, K)
+        v = jnp.where(act & (b1 == nb), ws1, plane_ref[rows, :])
+        plane_ref[rows, :] = jnp.where(act & (b2 == nb), ws2, v)
+        return c
+
+    lax.fori_loop(0, NB, body, 0)
 
 
 def oracle_runs(oracle):

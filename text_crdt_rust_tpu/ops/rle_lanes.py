@@ -42,6 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..common import ROOT_ORDER
+from ..obs.trace import span
 from .batch import (
     KIND_LOCAL,
     OpTensors,
@@ -328,8 +329,12 @@ class LanesResult:
 def _lane_tile(B: int) -> int:
     """Largest lane-block width <= 512 dividing B (full B when small).
 
-    512 lanes x ~1.7k-run planes keeps every per-op temporary a few MB;
-    the whole-B alternative spills registers past the VMEM budget at
+    VMEM bounds the tile: the blocked kernel holds four single-buffered
+    ``[capacity, T]`` int32 state planes (4 x capacity x T x 4 B) beside
+    its double-buffered ``[chunk, T]`` columns within its 110 MiB, so at
+    chunk 1024 T 512 fits planes of up to ~10k rows and T 128 up to ~50k
+    (the editing traces' fleet: 22,656 rows at T 128, 46.4 MB).
+    The whole-B alternative spills registers past the VMEM budget at
     2048 lanes (the round-3 config-5 compile failure)."""
     if B <= 512:
         return B
@@ -783,7 +788,9 @@ def _build_blocked_call(s_pad: int, B: int, capacity: int, block_k: int,
                         chunk: int, interpret: bool,
                         lane_tile: int | None = None, wmax: int = 1):
     """Shape-keyed cache for the blocked kernel (streaming chunks of one
-    geometry share one compiled kernel)."""
+    geometry share one compiled kernel).  The call is named
+    ``rle_lanes_replay``, in its jit and its Pallas kernel alike, so a
+    profile names it."""
     K = block_k
     NB = capacity // K
     NBT = max(8, NB)
@@ -791,8 +798,11 @@ def _build_blocked_call(s_pad: int, B: int, capacity: int, block_k: int,
     _require(B % T == 0, f"lane_tile {T} must divide batch {B}")
     col = lambda: pl.BlockSpec((chunk, T), lambda lb, i: (i, lb),
                                memory_space=pltpu.VMEM)
+    # One buffer for the state blocks: they move only between lane
+    # tiles, so a second buffer would only double the resident planes.
     whole = lambda rows: pl.BlockSpec(
-        (rows, T), lambda lb, i: (0, lb), memory_space=pltpu.VMEM)
+        (rows, T), lambda lb, i: (0, lb), pipeline_mode=pl.Buffered(1),
+        memory_space=pltpu.VMEM)
 
     call = pl.pallas_call(
         partial(_lanes_blocked_kernel, K=K, NB=NB, NBT=NBT, CHUNK=chunk,
@@ -825,8 +835,25 @@ def _build_blocked_call(s_pad: int, B: int, capacity: int, block_k: int,
             vmem_limit_bytes=110 * 1024 * 1024,
         ),
         interpret=interpret,
+        name="rle_lanes_replay",
     )
-    return jax.jit(lambda *a: call(*a))
+
+    def rle_lanes_replay(*args):
+        return call(*args)
+
+    return jax.jit(rle_lanes_replay)
+
+
+@dataclasses.dataclass(frozen=True)
+class LaneSteps:
+    """A pass's lane-steps: ``real`` is the steps the lanes' own streams
+    hold (the sum of their ``num_steps`` before ``stack_ops`` padded
+    them to the longest), ``stepped`` the steps the kernel runs
+    (``s_pad * B``).  The difference is padding: no-op steps of the
+    lanes whose histories are shorter."""
+
+    real: int
+    stepped: int
 
 
 def make_replayer_lanes_blocked(
@@ -845,6 +872,22 @@ def make_replayer_lanes_blocked(
     ``capacity`` counts run rows per lane and must be a ``block_k``
     multiple; growing per-chunk capacities grow NB at fixed K.  ``init``
     is a prior ``BlockedLanesResult.state()`` 6-tuple.
+
+    VMEM bounds ``capacity * T`` (T the lane tile, ``_lane_tile``): the
+    kernel keeps four ``[capacity, T]`` int32 state planes resident for
+    a lane tile's whole stream, single-buffered (the ``ord0``/``len0``
+    inputs and the ``ordp``/``lenp`` outputs: 4 x 1 x capacity x T x
+    4 B), beside the double-buffered ``[chunk, T]`` op columns and
+    origin outputs, within the kernel's 110 MiB.  The block gathers and
+    scatters loop over the NB blocks, so no temporary is plane-sized and
+    the code does not grow with NB.  Compiled for a described v5e at
+    T 128, block_k 128, chunk 1024: capacity 22,656 (177 blocks, the
+    editing traces' fleet) holds 46.4 MB of planes, and 51,200 rows
+    still fit.
+
+    The replayer's ``lane_steps`` (a ``LaneSteps``) counts the pass's
+    real and stepped lane-steps.  The first call is the ``rle.build``
+    span, every later one ``rle.dispatch``; staging is ``rle.stage``.
     """
     kinds = np.asarray(ops.kind)
     _require(kinds.ndim == 2, "rle_lanes takes stacked per-doc streams "
@@ -864,30 +907,41 @@ def make_replayer_lanes_blocked(
         a = np.asarray(get(ops), dtype=np.int32)
         return jnp.asarray(np.pad(a, ((0, s_pad - S), (0, 0))))
 
-    staged = (staged_col(lambda o: o.pos),
-              staged_col(lambda o: o.del_len),
-              staged_col(lambda o: o.ins_len),
-              staged_col(lambda o: o.ins_order_start),
-              staged_col(lambda o: o.rows_per_step))
-
     NBT = max(8, capacity // block_k)
-    if init is None:
-        init = _empty_blocked_state(capacity, NBT, B)
-    else:
-        init = _grow_blocked_state(init, capacity, block_k, B)
+    with span("rle.stage"):
+        staged = (staged_col(lambda o: o.pos),
+                  staged_col(lambda o: o.del_len),
+                  staged_col(lambda o: o.ins_len),
+                  staged_col(lambda o: o.ins_order_start),
+                  staged_col(lambda o: o.rows_per_step))
+        if init is None:
+            init = _empty_blocked_state(capacity, NBT, B)
+        else:
+            init = _grow_blocked_state(init, capacity, block_k, B)
     jitted = _build_blocked_call(s_pad, B, capacity, block_k, chunk,
                                  interpret, lane_tile, wmax)
+    built = False
 
     def run(state=None) -> BlockedLanesResult:
-        ini = init if state is None else _grow_blocked_state(
-            state, capacity, block_k, B)
-        ol, orr, ordp, lenp, nlog, blk, rws, liv, err = jitted(
-            *staged, *ini)
-        return BlockedLanesResult(
-            ordp=ordp, lenp=lenp, nlog=nlog, blkord=blk, rws=rws,
-            liv=liv, ol=ol[:S], orr=orr[:S], err=err, batch=B,
-            block_k=block_k)
+        # As ``ops.rle``'s replayer: the first call builds the program,
+        # every later one enqueues it (wall and thread CPU time).
+        nonlocal built
+        with span("rle.dispatch" if built else "rle.build", cpu=True):
+            ini = init if state is None else _grow_blocked_state(
+                state, capacity, block_k, B)
+            ol, orr, ordp, lenp, nlog, blk, rws, liv, err = jitted(
+                *staged, *ini)
+            res = BlockedLanesResult(
+                ordp=ordp, lenp=lenp, nlog=nlog, blkord=blk, rws=rws,
+                liv=liv, ol=ol[:S], orr=orr[:S], err=err, batch=B,
+                block_k=block_k)
+        built = True
+        return res
 
+    # Padding rows carry rows_per_step 0; every compiled step carries 1+.
+    run.lane_steps = LaneSteps(
+        real=int((np.asarray(ops.rows_per_step) > 0).sum()),
+        stepped=s_pad * B)
     return run
 
 
