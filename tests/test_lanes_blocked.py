@@ -383,3 +383,223 @@ class TestEditingTraceFleet:
         for b, t in enumerate(trace_fleet["lanes"]):
             assert (RL.expand_lane(res, b).tolist()
                     == trace_fleet["oracles"][t]), f"lane {b}"
+
+
+# ---------------------------------------------------------------------------
+# Bounded block walks: every per-lane gather and scatter of the local
+# blocked kernel visits only blocks [0, min(max nlog + 1, NB)) of its lane
+# tile.  Each case checks bit-identity with the un-blocked kernel and the
+# oracle where a lane's blocks, or the tile's largest nlog, move.
+# ---------------------------------------------------------------------------
+
+
+def at_front(n: int, every: int = 0):
+    """``n`` one-char inserts at position 0 (a new run, one row, each
+    time), with a two-char delete at position 2 after every ``every``-th
+    insert when ``every`` is set."""
+    from text_crdt_rust_tpu.utils.testdata import TestPatch
+
+    out = []
+    for k in range(n):
+        out.append(TestPatch(0, 0, "q"))
+        if every and k % every == every - 1:
+            out.append(TestPatch(2, 2, ""))
+    return out
+
+
+def typing(n: int):
+    """``n`` chars typed forward (one merged run), last one deleted."""
+    from text_crdt_rust_tpu.utils.testdata import TestPatch
+
+    return ([TestPatch(i, 0, "t") for i in range(n)]
+            + [TestPatch(n - 1, 1, "")])
+
+
+def packed_states(prefixes, *, K: int, NB: int):
+    """Host-packed warm starts of each lane's prefix: the blocked 6-tuple
+    (numpy, ``pack_lane_blocks``' identity order, ``nlog`` per lane) and
+    the un-blocked ``(ordp, lenp, rows)`` with the same runs."""
+    from text_crdt_rust_tpu.ops.lane_blocks import (
+        oracle_runs,
+        pack_lane_blocks,
+    )
+
+    cap, nbt, lanes = K * NB, max(8, NB), len(prefixes)
+    cols = []
+    planes = (np.zeros((cap, lanes), np.int32),
+              np.zeros((cap, lanes), np.int32),
+              np.zeros((1, lanes), np.int32))
+    nexts = []
+    for d, ps in enumerate(prefixes):
+        oracle = oracle_from_patches(ps)
+        starts, lens = oracle_runs(oracle)
+        packed, _ = pack_lane_blocks(starts, lens, K=K, NB=NB, NBT=nbt,
+                                     capacity=cap)
+        cols.append(packed[:6])
+        planes[0][:len(starts), d] = starts
+        planes[1][:len(starts), d] = lens
+        planes[2][0, d] = len(starts)
+        nexts.append(oracle.get_next_order())
+    blocked = tuple(np.stack([c[i].reshape(-1) for c in cols], axis=1)
+                    for i in range(6))
+    return blocked, planes, nexts
+
+
+def continue_stack(rests, nexts):
+    opses = []
+    for ps, nxt in zip(rests, nexts):
+        ops, _ = B.compile_local_patches(ps, lmax=8, dmax=None,
+                                         start_order=nxt)
+        opses.append(ops)
+    return B.stack_ops(opses)
+
+
+def replay_both(stacked, *, capacity, block_k, chunk, init=None,
+                planes=None):
+    blk = RL.make_replayer_lanes_blocked(
+        stacked, capacity=capacity, block_k=block_k, chunk=chunk,
+        init=init, interpret=True)()
+    ref = RL.make_replayer_lanes(stacked, capacity=capacity, chunk=chunk,
+                                 init=planes, interpret=True)()
+    blk.check()
+    ref.check()
+    return ref, blk
+
+
+def assert_matches_oracle(blk, histories):
+    for d, ps in enumerate(histories):
+        assert (RL.expand_lane(blk, d).tolist()
+                == oracle_signed(oracle_from_patches(ps))), f"lane {d}"
+
+
+def host_walks(n: int, K: int, NB: int):
+    """``(blocks visited, walks)`` of ``at_front(n)`` on lane 0 beside
+    lanes that never split, from the kernel's own split rule: slot 0
+    splits when its rows leave no room for a row (+ 1 spare), keeping
+    half; a split walks 4 times, an insert 6, each over ``min(max nlog
+    + 1, NB)`` blocks."""
+    rows, nlog, seen, walks = 0, 1, 0, 0
+    for _ in range(n):
+        if rows + 2 > K:
+            seen += 4 * min(nlog + 1, NB)
+            walks += 4
+            rows, nlog = rows // 2, nlog + 1
+        seen += 6 * min(nlog + 1, NB)
+        walks += 6
+        rows += 1
+    return seen, walks
+
+
+class TestBoundedBlockWalks:
+    K, NB = 8, 16
+
+    def test_skewed_pass(self):
+        # Lane 0 grows to many blocks, so every split lands its fresh
+        # block at the tile's largest nlog; the other lanes stay at one.
+        hist = [at_front(48, every=8), typing(5), typing(3), typing(6)]
+        stacked, _ = compile_stack(hist)
+        ref, blk = replay_both(stacked, capacity=self.K * self.NB,
+                               block_k=self.K, chunk=16)
+        nlog = np.asarray(blk.nlog)[0]
+        assert nlog[0] > 6 and (nlog[1:] == 1).all(), nlog
+        assert_same_doc(ref, blk, 4)
+        assert_matches_oracle(blk, hist)
+        assert blk.block_walk_share < 1.0
+
+    def test_warm_start_packed_nlogs_differ(self):
+        rng = random.Random(3030)
+        full = [random_patches(rng, n)[0] for n in (12, 30, 55, 80)]
+        cut = [4, 18, 40, 60]
+        blocked, planes, nexts = packed_states(
+            [ps[:c] for ps, c in zip(full, cut)], K=self.K, NB=2 * self.NB)
+        nlog0 = blocked[2][0]
+        assert len(set(nlog0.tolist())) > 2, nlog0
+        stacked = continue_stack([ps[c:] for ps, c in zip(full, cut)],
+                                 nexts)
+        ref, blk = replay_both(stacked, capacity=self.K * 2 * self.NB,
+                               block_k=self.K, chunk=8, init=blocked,
+                               planes=planes)
+        assert_same_doc(ref, blk, 4)
+        assert_matches_oracle(blk, full)
+
+    def test_split_on_the_fullest_lane(self):
+        # Lane 0 starts with the tile's largest nlog m and half-full
+        # blocks; its fifth insert at the front splits slot 0, and the
+        # moved half goes to physical block m = max(nlog): the "+ 1" of
+        # the bound.
+        from text_crdt_rust_tpu.utils.testdata import TestPatch
+
+        rng = random.Random(3031)
+        prefixes = [random_patches(rng, 40)[0], typing(4), typing(9),
+                    random_patches(rng, 6)[0]]
+        nb = 24
+        blocked, planes, nexts = packed_states(prefixes, K=self.K, NB=nb)
+        m = int(blocked[2][0, 0])
+        assert m == blocked[2].max() and m < nb - 1
+        assert (blocked[2][0, 1:] < m).all()
+        rests = [[TestPatch(0, 0, "z")] * 5, [TestPatch(0, 0, "y")],
+                 [TestPatch(1, 0, "x")], [TestPatch(0, 1, "")]]
+        stacked = continue_stack(rests, nexts)
+        ref, blk = replay_both(stacked, capacity=self.K * nb,
+                               block_k=self.K, chunk=8, init=blocked,
+                               planes=planes)
+        assert int(np.asarray(blk.nlog)[0, 0]) == m + 1
+        assert int(np.asarray(blk.blkord)[1, 0]) == m
+        assert_same_doc(ref, blk, 4)
+        assert_matches_oracle(blk, [p + r for p, r in zip(prefixes,
+                                                           rests)])
+
+    def test_walk_share_is_whole_when_a_lane_holds_every_block(self):
+        from text_crdt_rust_tpu.utils.testdata import TestPatch
+
+        rng = random.Random(3032)
+        nb = 6
+        per = (self.K - 1) // 2
+        # Lane 0: one-char inserts at the front, never merging, until
+        # its runs fill all nb blocks of the pack.
+        prefixes = [at_front(per * nb - 2), typing(3), typing(5),
+                    random_patches(rng, 5)[0]]
+        blocked, planes, nexts = packed_states(prefixes, K=self.K, NB=nb)
+        assert int(blocked[2][0, 0]) == nb
+        rests = [[TestPatch(1, 0, "z")] * 3, typing(2),
+                 [TestPatch(0, 1, "")], [TestPatch(0, 0, "w")]]
+        full = [p + r for p, r in zip(prefixes, rests)]
+        stacked = continue_stack(rests, nexts)
+        ref, blk = replay_both(stacked, capacity=self.K * nb,
+                               block_k=self.K, chunk=8, init=blocked,
+                               planes=planes)
+        assert blk.block_walk_share == 1.0
+        assert_same_doc(ref, blk, 4)
+        assert_matches_oracle(blk, full)
+
+    def test_walk_share_matches_host_count(self):
+        from text_crdt_rust_tpu.utils.testdata import TestPatch
+
+        n = 40
+        hist = [at_front(n)] + [[TestPatch(0, 0, "x")]] * 3
+        stacked, _ = compile_stack(hist)
+        ref, blk = replay_both(stacked, capacity=self.K * self.NB,
+                               block_k=self.K, chunk=16)
+        assert_same_doc(ref, blk, 4)
+        seen, walks = host_walks(n, self.K, self.NB)
+        splits = int(np.asarray(blk.nlog)[0, 0]) - 1
+        assert splits > 4 and walks == 6 * n + 4 * splits
+        # Every lane of the tile carries the tile's counts.
+        stats = np.asarray(blk.stats)
+        assert (stats[0] == seen).all()
+        assert (stats[1] == walks * self.NB).all()
+        assert blk.block_walk_share == pytest.approx(
+            seen / (walks * self.NB))
+        assert blk.block_walk_share < 0.5
+
+    def test_warm_start_refuses_blocks_past_nlog(self):
+        blocked, _, _ = packed_states([typing(3), typing(4)], K=self.K,
+                                      NB=self.NB)
+        bad = list(blocked)
+        bad[3] = bad[3].copy()
+        bad[3][0, 1] = 2  # lane 1 holds one block, yet names block 2
+        stacked = continue_stack([typing(1), typing(1)], [3, 4])
+        with pytest.raises(ValueError, match="lanes \\[1\\]"):
+            RL.make_replayer_lanes_blocked(
+                stacked, capacity=self.K * self.NB, block_k=self.K,
+                chunk=8, init=tuple(bad), interpret=True)

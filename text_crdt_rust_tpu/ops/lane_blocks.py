@@ -46,27 +46,33 @@ def _block(nb, K: int):
     return pl.ds(pl.multiple_of(nb * K, K), K)
 
 
-def gather_block(plane_ref, b, K: int, NB: int) -> jax.Array:
+def gather_block(plane_ref, b, K: int, NB: int, hi=None) -> jax.Array:
     """Per-lane block gather: ``out[j, lane] = plane[b[0,lane]*K + j,
     lane]`` as an NB-way select over the K-row blocks.  A loop over the
     blocks, not a Python unroll: the kernel's code and its compile stay
-    the same size at any NB (the editing traces reach 177 blocks)."""
+    the same size at any NB (the editing traces reach 177 blocks).
+
+    ``hi`` (a traced int32 scalar, default ``NB``) bounds the walk to
+    blocks ``[0, hi)``: a caller that knows no lane selects a block at
+    or above ``hi`` skips the rest.  The same holds for the other three
+    helpers."""
     return lax.fori_loop(
-        1, NB,
+        1, NB if hi is None else hi,
         lambda nb, ws: jnp.where(b == nb, plane_ref[_block(nb, K), :], ws),
         plane_ref[0:K, :])
 
 
-def gather_head(plane_ref, b, K: int, NB: int) -> jax.Array:
+def gather_head(plane_ref, b, K: int, NB: int, hi=None) -> jax.Array:
     """Row 0 of per-lane block ``b`` as a [1, B] vector."""
     return lax.fori_loop(
-        1, NB,
+        1, NB if hi is None else hi,
         lambda nb, h: jnp.where(
             b == nb, plane_ref[pl.ds(pl.multiple_of(nb * K, K), 1), :], h),
         plane_ref[0:1, :])
 
 
-def scatter_block(plane_ref, b, ws, act, K: int, NB: int) -> None:
+def scatter_block(plane_ref, b, ws, act, K: int, NB: int,
+                  hi=None) -> None:
     """Write ``ws`` back to per-lane block ``b`` on ``act`` lanes."""
 
     def body(nb, c):
@@ -75,11 +81,11 @@ def scatter_block(plane_ref, b, ws, act, K: int, NB: int) -> None:
                                        plane_ref[rows, :])
         return c
 
-    lax.fori_loop(0, NB, body, 0)
+    lax.fori_loop(0, NB if hi is None else hi, body, 0)
 
 
 def scatter_block2(plane_ref, b1, ws1, b2, ws2, act, K: int,
-                   NB: int) -> None:
+                   NB: int, hi=None) -> None:
     """Two-block scatter (block split: keep-half to ``b1``, moved half
     to the fresh block ``b2``; b1 != b2 per lane)."""
 
@@ -89,7 +95,7 @@ def scatter_block2(plane_ref, b1, ws1, b2, ws2, act, K: int,
         plane_ref[rows, :] = jnp.where(act & (b2 == nb), ws2, v)
         return c
 
-    lax.fori_loop(0, NB, body, 0)
+    lax.fori_loop(0, NB if hi is None else hi, body, 0)
 
 
 def oracle_runs(oracle):

@@ -497,10 +497,23 @@ def _lanes_blocked_kernel(
     blk0_ref, rws0_ref, liv0_ref,
     ol_ref, or_ref,                             # [CHUNK, B] outputs
     ordp, lenp, nlogv, blkord, rws, liv,        # state outputs (working)
-    err_ref,
+    err_ref, stats_ref,                         # [8, B] flags; walk counts
     cumliv,                                     # [NBT, B] scratch prefix
     *, K: int, NB: int, NBT: int, CHUNK: int, WMAX: int = 1,
 ):
+    """One lane tile's stream over its resident blocked state.
+
+    Invariant: every physical block a lane's ``blkord[0:nlog]`` names is
+    below its ``nlog``.  Blocks are allocated in order (a split's fresh
+    block is ``nlog``) and never freed, so fresh lanes, identity packs
+    (``lane_blocks.pack_lane_blocks``) and zero-padded growth
+    (``_grow_blocked_state``) keep it.  Hence no lane of the tile selects
+    a block at or above ``max(nlog)``, and every per-lane block walk is
+    bounded by ``min(max(nlog) + 1, NB)``, read from ``nlogv`` after any
+    split of the step (the ``+ 1`` is the fresh block a split writes
+    before ``nlog`` grows).  ``stats_ref`` rows 0 and 1 count the blocks
+    the walks visit and the blocks they would visit at ``NB``.
+    """
     from .lane_blocks import (
         gather_block,
         gather_head,
@@ -530,6 +543,15 @@ def _lanes_blocked_kernel(
         liv[:] = liv0_ref[:]
         cumliv[:] = _vcumsum(liv0_ref[:])
         err_ref[:] = jnp.zeros_like(err_ref)
+        stats_ref[:] = jnp.zeros_like(stats_ref)
+
+    def walk_bound(walks: int):
+        """The tile's block-walk bound for the next ``walks`` walks (no
+        split between them), counted in ``stats_ref``."""
+        hi = jnp.minimum(jnp.max(nlogv[:]) + 1, NB)
+        stats_ref[0:1, :] = stats_ref[0:1, :] + walks * hi
+        stats_ref[1:2, :] = stats_ref[1:2, :] + walks * NB
+        return hi
 
     def trow(tbl, l):
         """Per-lane slot read: ``tbl[l[0, b], b]`` as [1, B]."""
@@ -569,8 +591,9 @@ def _lanes_blocked_kernel(
             keep = r // 2
             mv = r - keep
             nbv = nlogv[:]  # per-lane fresh physical block id
-            ws_o = gather_block(ordp, b, K, NB)
-            ws_l = gather_block(lenp, b, K, NB)
+            hi = walk_bound(4)
+            ws_o = gather_block(ordp, b, K, NB, hi)
+            ws_l = gather_block(lenp, b, K, NB, hi)
             liv_hi = jnp.sum(
                 jnp.where((kdx >= keep) & (kdx < r) & (ws_o > 0), ws_l,
                           0), axis=0, keepdims=True)
@@ -578,10 +601,10 @@ def _lanes_blocked_kernel(
             up_l = vshift_up(ws_l, keep, K)
             scatter_block2(
                 ordp, b, jnp.where(kdx < keep, ws_o, 0),
-                nbv, jnp.where(kdx < mv, up_o, 0), do, K, NB)
+                nbv, jnp.where(kdx < mv, up_o, 0), do, K, NB, hi)
             scatter_block2(
                 lenp, b, jnp.where(kdx < keep, ws_l, 0),
-                nbv, jnp.where(kdx < mv, up_l, 0), do, K, NB)
+                nbv, jnp.where(kdx < mv, up_l, 0), do, K, NB, hi)
             # Logical tables: slots > l shift one down; cumliv shifts
             # with them (slot l+1 inherits old c_l — its correct
             # inclusive prefix after the split), slot l loses the
@@ -621,8 +644,9 @@ def _lanes_blocked_kernel(
                          lambda: find_insert_slot(p), lambda: (l, r0))
         b = trow(blkord, l)
         local = jnp.where(act, p - live_before(l), 0)
-        ws_o = gather_block(ordp, b, K, NB)
-        ws_l = gather_block(lenp, b, K, NB)
+        hi = walk_bound(6)
+        ws_o = gather_block(ordp, b, K, NB, hi)
+        ws_l = gather_block(lenp, b, K, NB, hi)
         lv = jnp.where(ws_o > 0, ws_l, 0)
         cum = _vcumsum(lv)
         i_r = jnp.sum(((cum < local) & (kdx < r0)).astype(jnp.int32),
@@ -641,8 +665,8 @@ def _lanes_blocked_kernel(
         # head row of the NEXT logical slot's block.
         nxt_in_blk = _vrow(ws_o, i_r + 1)
         b2 = trow(blkord, jnp.minimum(l + 1, NBT - 1))
-        nxt_slot_o = gather_head(ordp, b2, K, NB)
-        first_o = gather_head(ordp, trow(blkord, 0), K, NB)
+        nxt_slot_o = gather_head(ordp, b2, K, NB, hi)
+        first_o = gather_head(ordp, trow(blkord, 0), K, NB, hi)
         succ_p0 = jnp.where(trow(rws, 0) > 0, first_o, 0)
         succ_after = jnp.where(i_r + 1 < r0, nxt_in_blk,
                                jnp.where(l + 1 < nlogv[:], nxt_slot_o, 0))
@@ -650,8 +674,8 @@ def _lanes_blocked_kernel(
                          jnp.where(is_split, o_r + off, succ_after))
         right = jnp.where(succ == 0, root_u,
                           (jnp.abs(succ) - 1).astype(jnp.uint32))
-        scatter_block(ordp, b, no, act, K, NB)
-        scatter_block(lenp, b, nl, act, K, NB)
+        scatter_block(ordp, b, no, act, K, NB, hi)
+        scatter_block(lenp, b, nl, act, K, NB, hi)
         w_l = act & (tidx == l)
         rws[:] = jnp.where(w_l, rws[:] + amt, rws[:])
         liv[:] = jnp.where(w_l, liv[:] + il, liv[:])
@@ -681,8 +705,9 @@ def _lanes_blocked_kernel(
                          lambda: slot_of_live_rank(p + 1), lambda: l)
             b = trow(blkord, l)
             base = live_before(l)
-            ws_o = gather_block(ordp, b, K, NB)
-            ws_l = gather_block(lenp, b, K, NB)
+            hi = walk_bound(4)
+            ws_o = gather_block(ordp, b, K, NB, hi)
+            ws_l = gather_block(lenp, b, K, NB, hi)
             lv = jnp.where(ws_o > 0, ws_l, 0)
             cum = _vcumsum(lv)
             before = base + cum - lv
@@ -702,8 +727,8 @@ def _lanes_blocked_kernel(
                 a & (npart >= 1), i2, ws_o, ws_l, cs, ce, kdx)
             ws_o, ws_l, a1 = lane_apply_partial(
                 a & (npart == 2), i1, ws_o, ws_l, cs, ce, kdx)
-            scatter_block(ordp, b, ws_o, a, K, NB)
-            scatter_block(lenp, b, ws_l, a, K, NB)
+            scatter_block(ordp, b, ws_o, a, K, NB, hi)
+            scatter_block(lenp, b, ws_l, a, K, NB, hi)
             w_l = a & (tidx == l)
             rws[:] = jnp.where(w_l, rws[:] + a1 + a2, rws[:])
             liv[:] = jnp.where(w_l, liv[:] - tot, liv[:])
@@ -757,6 +782,7 @@ class BlockedLanesResult:
     ol: jax.Array       # u32[S, B]
     orr: jax.Array      # u32[S, B]
     err: jax.Array      # i32[8, B]  0: out of blocks; 1: bad delete
+    stats: jax.Array    # i32[8, B]  0: blocks walked; 1: ... at NB
     batch: int
     block_k: int
 
@@ -781,6 +807,16 @@ class BlockedLanesResult:
     def rows(self):
         """Total occupied rows per lane (compat with ``LanesResult``)."""
         return jnp.sum(self.rws, axis=0, keepdims=True)
+
+    @property
+    def block_walk_share(self) -> float:
+        """Blocks the pass's per-lane block walks visited ÷ the blocks
+        the same walks visit unbounded (all NB), over every lane tile:
+        1.0 where each tile's fullest lane held every block.  Each tile
+        writes its counts to all of its lanes, and tiles are equal, so
+        the lane sums keep the ratio."""
+        stats = np.asarray(self.stats, np.int64)
+        return float(stats[0].sum() / max(int(stats[1].sum()), 1))
 
 
 @functools.lru_cache(maxsize=32)
@@ -815,7 +851,7 @@ def _build_blocked_call(s_pad: int, B: int, capacity: int, block_k: int,
             col(), col(),
             whole(capacity), whole(capacity), whole(1),
             whole(NBT), whole(NBT), whole(NBT),
-            whole(8),
+            whole(8), whole(8),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((s_pad, B), jnp.uint32),
@@ -826,6 +862,7 @@ def _build_blocked_call(s_pad: int, B: int, capacity: int, block_k: int,
             jax.ShapeDtypeStruct((NBT, B), jnp.int32),
             jax.ShapeDtypeStruct((NBT, B), jnp.int32),
             jax.ShapeDtypeStruct((NBT, B), jnp.int32),
+            jax.ShapeDtypeStruct((8, B), jnp.int32),
             jax.ShapeDtypeStruct((8, B), jnp.int32),
         ],
         scratch_shapes=[
@@ -871,7 +908,9 @@ def make_replayer_lanes_blocked(
 
     ``capacity`` counts run rows per lane and must be a ``block_k``
     multiple; growing per-chunk capacities grow NB at fixed K.  ``init``
-    is a prior ``BlockedLanesResult.state()`` 6-tuple.
+    is a prior ``BlockedLanesResult.state()`` 6-tuple; one given as
+    numpy (a host-packed state) must keep the kernel's block invariant,
+    every block that ``blkord[0:nlog]`` names below ``nlog``.
 
     VMEM bounds ``capacity * T`` (T the lane tile, ``_lane_tile``): the
     kernel keeps four ``[capacity, T]`` int32 state planes resident for
@@ -886,8 +925,10 @@ def make_replayer_lanes_blocked(
     still fit.
 
     The replayer's ``lane_steps`` (a ``LaneSteps``) counts the pass's
-    real and stepped lane-steps.  The first call is the ``rle.build``
-    span, every later one ``rle.dispatch``; staging is ``rle.stage``.
+    real and stepped lane-steps; a result's ``block_walk_share`` the
+    share of all NB blocks its block walks visited.  The first call is
+    the ``rle.build`` span, every later one ``rle.dispatch``; staging is
+    ``rle.stage``.
     """
     kinds = np.asarray(ops.kind)
     _require(kinds.ndim == 2, "rle_lanes takes stacked per-doc streams "
@@ -929,12 +970,12 @@ def make_replayer_lanes_blocked(
         with span("rle.dispatch" if built else "rle.build", cpu=True):
             ini = init if state is None else _grow_blocked_state(
                 state, capacity, block_k, B)
-            ol, orr, ordp, lenp, nlog, blk, rws, liv, err = jitted(
+            ol, orr, ordp, lenp, nlog, blk, rws, liv, err, stats = jitted(
                 *staged, *ini)
             res = BlockedLanesResult(
                 ordp=ordp, lenp=lenp, nlog=nlog, blkord=blk, rws=rws,
-                liv=liv, ol=ol[:S], orr=orr[:S], err=err, batch=B,
-                block_k=block_k)
+                liv=liv, ol=ol[:S], orr=orr[:S], err=err, stats=stats,
+                batch=B, block_k=block_k)
         built = True
         return res
 
@@ -965,6 +1006,8 @@ def _grow_blocked_state(state, capacity: int, block_k: int, B: int):
              f"({block_k}) multiple — geometry K must not change "
              "between chunks")
     NBT = max(8, capacity // block_k)
+    if isinstance(nlog, np.ndarray) and isinstance(blk, np.ndarray):
+        _require_blocks_below_nlog(nlog, blk, B)
 
     def padp(a):
         a = jnp.asarray(a, jnp.int32)
@@ -986,6 +1029,20 @@ def _grow_blocked_state(state, capacity: int, block_k: int, B: int):
     return (padp(o0), padp(l0),
             jnp.asarray(nlog, jnp.int32).reshape(1, B),
             padt(blk), padt(rws), padt(liv))
+
+
+def _require_blocks_below_nlog(nlog, blk, B: int) -> None:
+    """The blocked kernel's invariant on a host-given state: each lane's
+    ``blkord[0:nlog]`` names blocks in ``[0, nlog)`` (``nlog`` 0 counts
+    as the one empty block of a fresh lane), so its block walks, bounded
+    by the tile's largest ``nlog``, miss no block in use."""
+    n = np.maximum(np.asarray(nlog, np.int64).reshape(B), 1)
+    blk = np.asarray(blk, np.int64)
+    named = np.arange(blk.shape[0])[:, None] < n[None, :]
+    bad = named & ((blk < 0) | (blk >= n[None, :]))
+    _require(not bad.any(),
+             f"init blkord names blocks at or above nlog on lanes "
+             f"{np.nonzero(bad.any(axis=0))[0][:8].tolist()}")
 
 
 def expand_lane_blocked(res, doc_index: int) -> np.ndarray:
